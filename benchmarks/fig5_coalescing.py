@@ -70,13 +70,13 @@ print("RESULT", json.dumps(out))
 def main(backend: str = "coarse"):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"        # forced host devices, never the chip
     env["PYTHONPATH"] = str(Path(__file__).resolve().parent.parent / "src")
     env["AAM_BACKEND"] = backend
     p = subprocess.run([sys.executable, "-c", textwrap.dedent(CHILD)],
                        capture_output=True, text=True, env=env, timeout=1200)
     if p.returncode != 0:
-        emit("fig5/ERROR", 0.0, p.stderr[-300:].replace("\n", " "))
-        return
+        raise RuntimeError(f"fig5 child failed: {p.stderr[-300:]}")
     line = [l for l in p.stdout.splitlines() if l.startswith("RESULT ")][-1]
     out = json.loads(line[len("RESULT "):])
     for k, v in out.items():

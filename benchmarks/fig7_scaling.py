@@ -85,13 +85,14 @@ def main(backend: str = "coarse", scale: int = 11):
     for p_ in (2, 4, 8):
         env = dict(env_base)
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={p_}"
+        env["JAX_PLATFORMS"] = "cpu"    # forced host devices, never the chip
         r = subprocess.run(
             [sys.executable, "-c", textwrap.dedent(
                 CHILD.format(P=p_, scale=scale, backend=backend))],
             capture_output=True, text=True, env=env, timeout=2400)
         if r.returncode != 0:
-            emit(f"fig7/P={p_}/ERROR", 0.0, r.stderr[-200:].replace("\n", " "))
-            continue
+            raise RuntimeError(f"fig7 P={p_} child failed: "
+                               f"{r.stderr[-200:]}")
         line = [l for l in r.stdout.splitlines()
                 if l.startswith("RESULT ")][-1]
         out = json.loads(line[len("RESULT "):])

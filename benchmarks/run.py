@@ -228,10 +228,11 @@ def _fused_rows(fu: dict, reps: int) -> list:
     from repro.core import commit as C
     from repro.core.commit import CommitSpec, commit
     from repro.core.messages import make_messages
+    from repro.kernels import interpret_default
 
     v, n, width, base = fu["v"], fu["n"], fu["width"], fu["base"]
     nrows = v // width
-    interp = jax.default_backend() != "tpu"
+    interp = interpret_default()
     rng = np.random.default_rng(7)
     rows: list = []
     fsp = CommitSpec(backend="fused", sort=False, stats=False,
@@ -542,16 +543,18 @@ def _fig7_json(f7: dict):
                                  "src")
     for p_ in f7["ps"]:
         env = dict(env_base)
+        # forced host devices: the child stays off any accelerator the
+        # parent may hold
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={p_}"
+        env["JAX_PLATFORMS"] = "cpu"
         r = subprocess.run(
             [sys.executable, "-c", textwrap.dedent(_F7_CHILD.format(
                 P=p_, scale=f7["scale"], reps=f7["reps"],
                 backends=tuple(f7["backends"])))],
             capture_output=True, text=True, env=env, timeout=1200)
         if r.returncode != 0:
-            print(f"fig7 P={p_} child failed: {r.stderr[-400:]}",
-                  file=sys.stderr)
-            continue
+            raise RuntimeError(f"fig7 P={p_} child failed: "
+                               f"{r.stderr[-400:]}")
         line = [l for l in r.stdout.splitlines()
                 if l.startswith("RESULT ")][-1]
         yield p_, json.loads(line[len("RESULT "):])
@@ -570,6 +573,8 @@ def main() -> None:
     ap.add_argument("--sizes", default="tiny", choices=tuple(JSON_SIZES),
                     help="problem sizes for --json")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.json:
         doc = bench_json(args.sizes)
         diff = _diff_vs_previous(doc, args.json)

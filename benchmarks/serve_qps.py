@@ -663,6 +663,8 @@ if __name__ == "__main__":
                     help="with --crash-resume/--open-loop: merge the "
                          "rows into this aam-bench/v1 trajectory file")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.open_loop:
         kinds = tuple((args.kinds or "bfs").split(","))
         rows = open_loop(kinds,

@@ -12,6 +12,7 @@ import sys
 if os.environ.get("_REPRO_CHILD") != "1":
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"        # forced host devices, never the chip
     env["_REPRO_CHILD"] = "1"
     raise SystemExit(subprocess.run([sys.executable] + sys.argv,
                                     env=env).returncode)

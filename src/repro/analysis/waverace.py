@@ -47,6 +47,7 @@ from types import SimpleNamespace
 import numpy as np
 
 import jax
+from jax.extend.core import Literal
 import jax.numpy as jnp
 
 from repro.core import engine as E
@@ -104,7 +105,7 @@ def _in_scope(eqn) -> bool:
 
 
 def _vars(atoms):
-    return [a for a in atoms if not isinstance(a, jax.core.Literal)]
+    return [a for a in atoms if not isinstance(a, Literal)]
 
 
 def _walk(jaxpr, chain: set, rep: RaceReport, where: str,
@@ -154,7 +155,7 @@ def _walk(jaxpr, chain: set, rep: RaceReport, where: str,
 
         if prim in SCATTER_PRIMS:
             operand = eqn.invars[0]
-            if not isinstance(operand, jax.core.Literal) \
+            if not isinstance(operand, Literal) \
                     and operand in chain:
                 if eqn_scoped:
                     rep.commits += 1
@@ -187,12 +188,12 @@ def _call_jaxpr(eqn):
 
 def _map_in(inner_jaxpr, outer_invars, chain):
     return {iv for iv, ov in zip(inner_jaxpr.invars, outer_invars)
-            if not isinstance(ov, jax.core.Literal) and ov in chain}
+            if not isinstance(ov, Literal) and ov in chain}
 
 
 def _map_out(inner_jaxpr, inner_chain, eqn, chain):
     for ov, res in zip(eqn.outvars, inner_jaxpr.outvars):
-        if not isinstance(res, jax.core.Literal) and res in inner_chain:
+        if not isinstance(res, Literal) and res in inner_chain:
             chain.add(ov)
 
 
@@ -218,7 +219,7 @@ def _walk_while(eqn, chain, rep, where, scoped=False):
         # feed body outputs (carry') back into carry invars
         carry_in = body.invars[bn:]
         for civ, res in zip(carry_in, body.outvars):
-            if not isinstance(res, jax.core.Literal) and res in inner:
+            if not isinstance(res, Literal) and res in inner:
                 inner.add(civ)
         if inner == snapshot:
             break
@@ -227,7 +228,7 @@ def _walk_while(eqn, chain, rep, where, scoped=False):
     # while outvars = final carry
     carry_results = body.outvars
     for ov, res in zip(eqn.outvars, carry_results):
-        if not isinstance(res, jax.core.Literal) and res in inner:
+        if not isinstance(res, Literal) and res in inner:
             chain.add(ov)
 
 
@@ -241,12 +242,12 @@ def _walk_scan(eqn, chain, rep, where, scoped=False):
         _walk(body, inner, rep, where, scoped)
         carry_in = body.invars[nc:nc + ncar]
         for civ, res in zip(carry_in, body.outvars[:ncar]):
-            if not isinstance(res, jax.core.Literal) and res in inner:
+            if not isinstance(res, Literal) and res in inner:
                 inner.add(civ)
         if inner == snapshot:
             break
     for ov, res in zip(eqn.outvars, body.outvars):
-        if not isinstance(res, jax.core.Literal) and res in inner:
+        if not isinstance(res, Literal) and res in inner:
             chain.add(ov)
 
 

@@ -61,6 +61,18 @@ from repro.core.messages import Messages, make_messages
 # benchmarks/fig4_coarsening.py.
 M_LADDER: tuple = (16, 64, 256, 1024, 4096, None)
 
+# Transactions run one after another (a ``lax.scan`` over tiles, or one
+# kernel grid row per tile), so a ladder level that splits an n-message
+# batch into more than this many of them costs more in per-transaction
+# steps than its conflicts could: the policy drops such levels.
+MAX_TRANSACTIONS = 4096
+
+# The kernel tiers walk a grid of state blocks x message tiles, i.e.
+# V * N / (block_v * tile_m) steps; calibration fits are affine in N at
+# a fixed small V and cannot see that product, so above this many steps
+# the kernel tiers leave the candidate set.
+KERNEL_MAX_GRID_STEPS = 1 << 18
+
 # Conflict-density waterlines (conflicts / routed messages per round).
 # Above HIGH the serialization analogue dominates -> shrink M; below LOW
 # transactions are conflict-free -> amortize more dispatch overhead per
@@ -247,7 +259,9 @@ class AutoTuner:
         # (MXU-path accumulate) and vector payloads a different memory
         # shape than the `min` scalar workload, so they get their own
         # affine fits instead of inheriting min's backend pick
-        return (f"{jax.default_backend()}|sort={sort}|stats={stats}"
+        # fits are portable within one accelerator class: key by the
+        # device kind ("TPU v5 lite", "cpu"), not the backend name
+        return (f"{jax.devices()[0].device_kind}|sort={sort}|stats={stats}"
                 f"|tile_m={tile_m}|block_v={block_v}|interpret={interpret}"
                 f"|ns={list(self.ns)}|v={self.v_cal}"
                 f"|op={op}|dtype={np.dtype(dtype).name}|w={width}")
@@ -522,16 +536,25 @@ class AutoTuner:
         # scatter, conflicts=0) density reads 0.0 forever — degrade
         # honestly to the calibrated static M* instead of pretending.
         has_telemetry = spec.stats or (backend == "coarse" and spec.sort)
-        level = next((i for i, m in enumerate(M_LADDER)
-                      if m is not None and m >= m_star), len(M_LADDER) - 1)
+        ladder = feasible_ladder(n)
+        level = next((i for i, m in enumerate(ladder)
+                      if m is not None and m >= m_star), len(ladder) - 1)
         if m_star >= n:          # whole batch fits one transaction
-            level = len(M_LADDER) - 1
-        return TunerPolicy(backend=backend, ladder=M_LADDER,
+            level = len(ladder) - 1
+        return TunerPolicy(backend=backend, ladder=ladder,
                            init_level=level, adaptive=has_telemetry,
                            sanitize=spec.sanitize, **base)
 
 
 DEFAULT_TUNER = AutoTuner()
+
+
+def feasible_ladder(n: int) -> tuple:
+    """The :data:`M_LADDER` levels that split ``n`` messages into at most
+    :data:`MAX_TRANSACTIONS` transactions (the whole batch always
+    stays)."""
+    return tuple(m for m in M_LADDER
+                 if m is None or -(-n // m) <= MAX_TRANSACTIONS)
 
 # The kernel tiers share one interpret-vs-compiled story: both run the
 # same Pallas tile loop (fused additionally folds the route-side key
@@ -560,11 +583,8 @@ def _kernel_compiled(spec: CommitSpec) -> bool:
     so both kernel tiers stay out of the candidate set unless the kernel
     actually compiles (or the :data:`_ALLOW_INTERP_ENV` escape hatch is
     set)."""
-    if _allow_interp():
-        return True
-    if spec.interpret is not None:
-        return not spec.interpret
-    return jax.default_backend() == "tpu"
+    from repro.kernels import interpret_default
+    return _allow_interp() or not interpret_default(spec.interpret)
 
 
 # Back-compat alias (pre-fused name).
@@ -599,6 +619,18 @@ def policy_for(spec: CommitSpec, state, msgs: Messages | None = None, *,
         pallas_ok = (getattr(state, "ndim", 1) == 1
                      and state.dtype in (jnp.int32, jnp.float32))
         n = 1 if n is None else n
+    v = getattr(state, "shape", None)
+    v = v[0] if v else None         # [V] or [W*V] composite key space
+    if pallas_ok and v is not None:
+        from repro.kernels.coarse_commit import commit_grid
+        steps = int(np.prod(commit_grid(v, n, spec.tile_m, spec.block_v)))
+        if steps > KERNEL_MAX_GRID_STEPS:
+            tuner._audit({
+                "event": "kernel_tiers_excluded",
+                "backends": list(KERNEL_BACKENDS), "op": op,
+                "reason": f"grid of {steps} steps exceeds "
+                          f"{KERNEL_MAX_GRID_STEPS}"})
+            pallas_ok = False
     if pallas_ok and not _kernel_compiled(spec):
         # autotune-on-interpret fix: the kernel tiers would run in
         # interpret mode here — exclude them rather than fit the cost
@@ -611,8 +643,6 @@ def policy_for(spec: CommitSpec, state, msgs: Messages | None = None, *,
                       "would be simulator artifacts",
             "escape_hatch": _ALLOW_INTERP_ENV})
         pallas_ok = False
-    v = getattr(state, "shape", None)
-    v = v[0] if v else None         # [V] or [W*V] composite key space
     return tuner.policy(spec, n=n, pallas_ok=pallas_ok, v=v, op=op,
                         dtype=dtype, width=width, axis_width=axis_width)
 

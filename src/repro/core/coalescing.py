@@ -378,11 +378,11 @@ def _bucket_counts(owner_c: jax.Array, valid: jax.Array, num_buckets: int,
         raise ValueError(
             f"count_backend={backend!r} not in {_COUNT_BACKENDS}")
     if backend == "pallas":
+        from repro.kernels import interpret_default
         from repro.kernels.coalesce import bucket_count_pallas
         masked = jnp.where(valid, owner_c, -1).astype(jnp.int32)
-        interp = jax.default_backend() != "tpu"
         return bucket_count_pallas(masked, num_buckets=num_buckets,
-                                   interpret=interp)
+                                   interpret=interpret_default())
     return jnp.bincount(owner_c, length=num_buckets + 1)[:num_buckets]
 
 

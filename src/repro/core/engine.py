@@ -40,8 +40,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 from repro.core import autotune as AT
 from repro.core import commit as C
 from repro.obs import trace as OT
@@ -317,36 +315,51 @@ def gather_until_answered(ecfg: EngineConfig, arr_l, idx, valid, fill=0,
 # then a process-level feedback cache grows it for the NEXT run whenever a
 # run's waves persistently overflowed (sub-rounds per round above
 # OVERFLOW_RATIO means messages kept getting requeued past C) — the same
-# measure-then-adapt loop the autotuner closes for backend/M.
+# measure-then-adapt loop the autotuner closes for backend/M.  C never
+# exceeds the largest shard's edge count (rounded up to a power of two):
+# one wave of one-message-per-edge traffic then always fits a sub-round,
+# so a round delivers everything within ``max_subrounds`` at any scale.
 CAPACITY_MIN = 64
-CAPACITY_MAX = 1 << 15
 OVERFLOW_RATIO = 2.0
 _CAPACITY_CACHE: dict = {}
 
 
-def auto_capacity(g, num_shards: int) -> int:
+def _pow2_at_least(n: int) -> int:
+    return 1 << (max(int(n), 1) - 1).bit_length()
+
+
+def capacity_cap(shard_edges: int) -> int:
+    """Largest C ``capacity="auto"`` picks for shards of ``shard_edges``
+    edges."""
+    return max(CAPACITY_MIN, _pow2_at_least(shard_edges))
+
+
+def auto_capacity(g, num_shards: int, shard_edges: int) -> int:
     """Current C for (graph shape, shard count): the cached feedback value
     when a previous run reported overflow, the static heuristic otherwise
-    (power of two ~2x the average per-shard inbound load, clamped)."""
+    (power of two ~2x the average per-shard inbound load, clamped to
+    :func:`capacity_cap`).  ``shard_edges`` is the fullest shard's edge
+    count (the padded width of :func:`repro.graphs.csr.partition_edges`)."""
     key = (g.num_vertices, g.num_edges, num_shards)
     hit = _CAPACITY_CACHE.get(key)
     if hit is not None:
         return hit
     per_shard = max(1, (2 * g.num_edges) // max(num_shards, 1))
-    return max(CAPACITY_MIN, min(1 << (per_shard - 1).bit_length(),
-                                 CAPACITY_MAX))
+    return min(max(CAPACITY_MIN, _pow2_at_least(per_shard)),
+               capacity_cap(shard_edges))
 
 
 def _capacity_feedback(g, num_shards: int, capacity: int,
-                       subrounds: int, rounds: int) -> None:
-    """Grow the cached C when waves persistently overflowed this run.
+                       subrounds: int, rounds: int, cap: int) -> None:
+    """Grow the cached C (up to ``cap``) when waves persistently
+    overflowed this run.
 
     Algorithms issuing several waves per round (Boruvka) inflate the
     sub-round count without real overflow; the growth is monotone and
     capped, so a spurious doubling costs padding, never correctness."""
-    if subrounds > OVERFLOW_RATIO * max(rounds, 1) and capacity < CAPACITY_MAX:
+    if subrounds > OVERFLOW_RATIO * max(rounds, 1) and capacity < cap:
         _CAPACITY_CACHE[(g.num_vertices, g.num_edges, num_shards)] = \
-            min(capacity * 2, CAPACITY_MAX)
+            min(capacity * 2, cap)
 
 
 # ---------------------------------------------------------------------------
@@ -612,7 +625,7 @@ class _Runner:
 
         st_specs = jax.tree.map(lambda _: Ps(axis), self.state0)
         sc_specs = jax.tree.map(lambda _: Ps(), self.scalars0)
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(st_specs, sc_specs, (Ps(),) * 6, Ps())
             + (Ps(axis),) * 5,
@@ -732,8 +745,8 @@ def run_distributed(alg: AlgorithmSpec, mesh, g, *,
     re-derives the 1-D ownership for the smaller mesh, re-homes the last
     snapshot onto it (see :func:`_remap_state`; per-edge state restarts
     from round 0), and finishes there.  ``DistributedResult.degraded``
-    reports it.  With neither parameter set the loop runs single-shot,
-    exactly as before.
+    reports it.  With neither parameter set the loop runs single-shot and
+    any error propagates at once.
     """
     from repro.graphs.csr import GraphSet, partition_edges
 
@@ -747,11 +760,13 @@ def run_distributed(alg: AlgorithmSpec, mesh, g, *,
         # here instead of executing the mesh program.
         raise LintCapture(alg, g, batch)
     P = mesh.shape[axis]
-    auto_cap = capacity == "auto"
-    if auto_cap:
-        capacity = auto_capacity(g, P)
     if edges is None:
         edges = partition_edges(g, P)
+    auto_cap = capacity == "auto"
+    if auto_cap:
+        shard_edges = edges[0][0].shape[1]       # [P, emax] src array
+        cap = capacity_cap(shard_edges)
+        capacity = auto_capacity(g, P, shard_edges)
     kw = dict(axis=axis, capacity=capacity, m=m, spec=spec, batch=batch,
               max_subrounds=max_subrounds)
     r = _Runner(alg, mesh, g, edges=edges, **kw)
@@ -760,6 +775,10 @@ def run_distributed(alg: AlgorithmSpec, mesh, g, *,
     chunk = (snapshot_rounds if snapshot_rounds
              else max(r.max_rounds, 1))
     snap = (state, scalars, carry)
+    # only a run that asked for fault tolerance treats an error as a
+    # host drop; otherwise the first error (a compile failure, an OOM)
+    # propagates instead of shrinking the mesh or retrying in place
+    tolerate = fault_injector is not None or snapshot_rounds is not None
     while bool(carry[5]) and int(carry[4]) < r.max_rounds:
         limit = min(int(carry[4]) + chunk, r.max_rounds)
         try:
@@ -772,7 +791,7 @@ def run_distributed(alg: AlgorithmSpec, mesh, g, *,
             raise
         except Exception:
             faults += 1
-            if faults > max_faults:
+            if not tolerate or faults > max_faults:
                 raise
             degraded = True
             tr = OT.get_tracer()
@@ -800,7 +819,8 @@ def run_distributed(alg: AlgorithmSpec, mesh, g, *,
         chunk_i += 1
     conflicts, subrounds, dall, level, rounds, _ = carry
     if auto_cap:
-        _capacity_feedback(g, P, capacity, int(subrounds), int(rounds))
+        _capacity_feedback(g, P, capacity, int(subrounds), int(rounds),
+                           cap)
     return DistributedResult(state=state, scalars=scalars, rounds=rounds,
                              conflicts=conflicts, subrounds=subrounds,
                              delivered_all=dall, m_final=r.m_final(level),

@@ -21,7 +21,6 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from jax.sharding import PartitionSpec as Ps
 
 from repro.core import commit as C
@@ -93,9 +92,9 @@ def run_transactions(mesh, txns, num_vertices: int, *, axis: str = "data",
         all_done = jax.lax.psum(jnp.sum(done.astype(jnp.int32)), axis)
         return visited, rounds, retries, bids, all_done
 
-    fn = compat.shard_map(shard_fn, mesh=mesh, in_specs=(Ps(axis),),
-                       out_specs=(Ps(axis), Ps(), Ps(), Ps(), Ps()),
-                       check_vma=False)
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=(Ps(axis),),
+                    out_specs=(Ps(axis), Ps(), Ps(), Ps(), Ps()),
+                    check_vma=False)
     visited, rounds, retries, bids, all_done = jax.jit(fn)(txns)
     assert int(all_done) == total, (int(all_done), total)
     return (visited[:num_vertices],

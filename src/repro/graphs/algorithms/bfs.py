@@ -89,6 +89,10 @@ def multi_source_bfs(g: Graph, sources, *, commit: str = "coarse",
     frontier0 = jnp.zeros((lanes, v), bool).at[lidx, sources].set(True)
     e = g.src.shape[0]
     dst_l = jnp.broadcast_to(g.dst, (lanes, e))
+    # [L, E] flat ids into the lane-major state: a scalar gather keeps
+    # the edge axis minor (``x[:, g.src]`` lays [L, E] out lane-minor,
+    # which a TPU pads 32-fold)
+    src_l = lidx[:, None] * v + g.src[None, :]
     step, lvl0 = AT.make_commit_step(spec, "min", dist0.reshape(-1),
                                      n=lanes * e, axis_width=lanes)
 
@@ -98,8 +102,8 @@ def multi_source_bfs(g: Graph, sources, *, commit: str = "coarse",
 
     def body(state):
         dist, frontier, it, lvl, nmsg, ncf, nap = state
-        active = frontier[:, g.src]            # per-lane early-exit mask
-        msgs = lane_messages(dst_l, dist[:, g.src] + 1, active, v)
+        active = frontier.reshape(-1)[src_l]   # per-lane early-exit mask
+        msgs = lane_messages(dst_l, dist.reshape(-1)[src_l] + 1, active, v)
         res, lvl = step(dist.reshape(-1), msgs, lvl)
         dist2 = res.state.reshape(lanes, v)
         return (dist2, dist2 != dist, it + 1, lvl,

@@ -40,24 +40,33 @@ class Graph:
 def from_edges(src: np.ndarray, dst: np.ndarray, num_vertices: int,
                weights: np.ndarray | None = None, *,
                symmetrize: bool = False, dedupe: bool = True) -> Graph:
-    src = np.asarray(src, np.int64)
-    dst = np.asarray(dst, np.int64)
-    if weights is None:
+    src = np.asarray(src)
+    dst = np.asarray(dst)
+    unweighted = weights is None
+    if unweighted:
         weights = np.ones(src.shape, np.float32)
     keep = src != dst                       # drop self-loops
     src, dst, weights = src[keep], dst[keep], weights[keep]
     if symmetrize:
         src, dst = np.concatenate([src, dst]), np.concatenate([dst, src])
         weights = np.concatenate([weights, weights])
-    if dedupe and len(src):
+    if dedupe and unweighted:
+        # no weights to carry along: the sorted distinct keys alone give
+        # the src-ordered edge list (a plain sort, no stable argsort)
+        key = np.unique(src.astype(np.int64) * num_vertices + dst)
+        src, dst = np.divmod(key, num_vertices)
+        weights = np.ones(src.shape, np.float32)
+    elif dedupe and len(src):
+        src = src.astype(np.int64)
+        # np.unique returns the keys sorted, i.e. already ordered by src
         key = src * num_vertices + dst
         _, idx = np.unique(key, return_index=True)
         src, dst, weights = src[idx], dst[idx], weights[idx]
-    order = np.argsort(src, kind="stable")
-    src, dst, weights = src[order], dst[order], weights[order]
+    else:
+        order = np.argsort(src, kind="stable")
+        src, dst, weights = src[order], dst[order], weights[order]
     indptr = np.zeros(num_vertices + 1, np.int64)
-    np.add.at(indptr, src + 1, 1)
-    indptr = np.cumsum(indptr)
+    indptr[1:] = np.cumsum(np.bincount(src, minlength=num_vertices))
     return Graph(
         indptr=jnp.asarray(indptr, jnp.int32),
         src=jnp.asarray(src, jnp.int32),

@@ -4,31 +4,53 @@ DESIGN.md §7: degree-distribution + diameter-regime matched synthetics).
 """
 from __future__ import annotations
 
+import os
+from concurrent.futures import ThreadPoolExecutor
+
 import numpy as np
 
 from repro.graphs.csr import Graph, from_edges
 
 
+KRONECKER_CHUNK = 1 << 20      # edges per independent random stream
+
+
+def _kronecker_chunk(src, dst, scale, rng, a, ab, abc):
+    """Fill one chunk of edge endpoints: per level, one uniform draw picks
+    the quadrant (a, b, c, d) of the recursive adjacency matrix."""
+    for _ in range(scale):
+        r = rng.random(src.shape[0], dtype=np.float32)
+        bottom = r >= ab
+        right = (r >= abc) | ((r >= a) & ~bottom)
+        src <<= 1
+        src |= bottom
+        dst <<= 1
+        dst |= right
+
+
 def kronecker(scale: int, edge_factor: int = 16, seed: int = 0,
               a=0.57, b=0.19, c=0.19) -> Graph:
-    """Graph500 Kronecker generator (power-law degree distribution)."""
-    rng = np.random.default_rng(seed)
+    """Graph500 Kronecker generator (power-law degree distribution).
+
+    Edges are drawn in chunks of :data:`KRONECKER_CHUNK`, each from its
+    own stream seeded by ``(seed, chunk)``, on the host's cores; the
+    graph depends on ``seed`` only, not on the number of threads."""
     n = 1 << scale
     m = edge_factor * n
-    src = np.zeros(m, np.int64)
-    dst = np.zeros(m, np.int64)
-    ab, cn = a + b, a + b + c
-    for _ in range(scale):
-        r = rng.random(m)
-        ii = (r >= ab).astype(np.int64)             # bottom half
-        r2 = rng.random(m)
-        jj = np.where(ii == 1, (r2 >= c / (1 - ab)).astype(np.int64),
-                      (r2 >= a / ab).astype(np.int64))
-        src = 2 * src + ii
-        dst = 2 * dst + jj
-    perm = rng.permutation(n)                       # relabel
-    src, dst = perm[src], perm[dst]
-    return from_edges(src, dst, n, symmetrize=True)
+    itype = np.int32 if scale < 31 else np.int64
+    src = np.zeros(m, itype)
+    dst = np.zeros(m, itype)
+    starts = range(0, m, KRONECKER_CHUNK)
+
+    def fill(start):
+        sl = slice(start, start + KRONECKER_CHUNK)
+        rng = np.random.default_rng([seed, start // KRONECKER_CHUNK])
+        _kronecker_chunk(src[sl], dst[sl], scale, rng, a, a + b, a + b + c)
+
+    with ThreadPoolExecutor(max_workers=os.cpu_count() or 1) as pool:
+        list(pool.map(fill, starts))
+    perm = np.random.default_rng(seed).permutation(n).astype(itype)
+    return from_edges(perm[src], perm[dst], n, symmetrize=True)
 
 
 def erdos_renyi(n: int, avg_degree: float = 8.0, seed: int = 0) -> Graph:

@@ -24,8 +24,8 @@ ids) and, per grid step,
 3. applies the commit op (``min``/``max``/``add``/``or``/``first`` with
    the pinned lowest-global-message-id ``first`` tiebreak, identical to
    the coarse kernel so cross-backend parity holds bit-for-bit);
-4. (``stats=True``) reduces the in-transaction duplicate-target count —
-   the abort-statistics analogue — into a per-(block, tile) output.
+4. (``stats=True``) accumulates the in-transaction duplicate-target
+   count — the abort-statistics analogue — per state column.
 
 Grid/tiling/identity-padding follow :mod:`repro.kernels.coarse_commit`:
 grid = (state_blocks, message_tiles), message tiles innermost so a state
@@ -34,8 +34,10 @@ working set is the HTM speculative-capacity analogue and M is the
 paper's transaction-size knob (the adaptive ladder moves it per round).
 
 ``base`` is a traced scalar (the owner shard's first global vertex id,
-``shard * block`` under ``shard_map``) carried as a (1,) int32 input so
-the same compiled kernel serves every shard.  With ``base=None`` and
+``shard * block`` under ``shard_map``) carried as a (1,) int32 array in
+SMEM so the same compiled kernel serves every shard.  Message arrays
+and the state travel as lane-dense rows, the layout
+:mod:`repro.kernels.coarse_commit` explains.  With ``base=None`` and
 ``width == 1`` the key computation folds away and the kernel degenerates
 to the plain coarse-commit tile loop — that specialization is what
 ``CommitSpec(backend="fused")`` runs through the generic
@@ -48,8 +50,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.coarse_commit import _RANK_INF, _identity
+from repro.kernels.coarse_commit import (COMMIT_OPS, check_block,
+                                         commit_grid, commit_window,
+                                         message_window, pad_message_row,
+                                         pad_state_row, vmem_limit)
 
 
 def _fused_kernel(*refs, op: str, tile_m: int, block_v: int, width: int,
@@ -63,19 +69,20 @@ def _fused_kernel(*refs, op: str, tile_m: int, block_v: int, width: int,
     conf_ref = next(it) if stats else None
 
     b = pl.program_id(0)
-    m = pl.program_id(1)
+    w = pl.program_id(1)
 
-    @pl.when(m == 0)
+    @pl.when(w == 0)
     def _init():
         out_ref[...] = state_ref[...]
+        if conf_ref is not None:
+            conf_ref[...] = jnp.zeros_like(conf_ref)
 
-    idx = idx_ref[...]                                   # [M] global ids
-    val = val_ref[...]                                   # [M]
+    idx = idx_ref[...].T                                 # [W, 1] global ids
     # --- in-kernel composite key: the fused local_idx/fuse_keys step ---
     rel = idx - (base_ref[0] if with_base else 0)
     ok = (idx >= 0) & (rel >= 0) & (rel < nrows)         # -1 = empty slot
     if with_lane:
-        lane = lane_ref[...]
+        lane = lane_ref[...].T
         ok = ok & (lane >= 0) & (lane < width)
         key = rel * width + jnp.where(ok, lane, 0)
     else:
@@ -84,69 +91,17 @@ def _fused_kernel(*refs, op: str, tile_m: int, block_v: int, width: int,
     kk = key - b * block_v
     mask = ok & (kk >= 0) & (kk < block_v)
 
-    if op not in ("add", "min", "max", "or", "first"):
-        raise ValueError(op)
-
-    if conf_ref is not None:
-        conf_ref[0, 0] = 0
-
     # Tile skip — the fusion dividend the unfused path cannot claim:
     # bucketed traffic is clustered (contention concentrates keys in few
     # state blocks), so most (block, tile) grid steps touch nothing and
     # the whole M×B incidence/commit is elided.  The separate-launch
     # pipeline can't do this: its commit kernel sees pre-flattened keys
     # with no cheap per-tile routing test left.
-    @pl.when(jnp.any(mask))
+    @pl.when(jnp.max(mask.astype(jnp.int32)) > 0)
     def _commit_tile():
-        kkc = jnp.where(mask, kk, 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, (tile_m, block_v), 1)
-        onehot = (col == kkc[:, None]) & mask[:, None]   # [M, B]
-
-        if conf_ref is not None:
-            cnt = jnp.sum(onehot.astype(jnp.int32), axis=0)  # [B]
-            conf_ref[0, 0] = jnp.sum(jnp.where(cnt > 1, cnt, 0))
-
-        if op == "add":
-            if jnp.issubdtype(val.dtype, jnp.floating):
-                contrib = jax.lax.dot(
-                    val[None, :].astype(jnp.float32),
-                    onehot.astype(jnp.float32),
-                    precision=jax.lax.Precision.HIGHEST)[0]  # MXU path
-            else:
-                contrib = jnp.sum(jnp.where(onehot, val[:, None], 0),
-                                  axis=0)
-            out_ref[...] += contrib.astype(out_ref.dtype)
-        elif op == "min":
-            ident = _identity(op, val.dtype)
-            cand = jnp.where(onehot, val[:, None], ident)
-            out_ref[...] = jnp.minimum(out_ref[...],
-                                       jnp.min(cand, axis=0))
-        elif op == "max":
-            ident = _identity(op, val.dtype)
-            cand = jnp.where(onehot, val[:, None], ident)
-            out_ref[...] = jnp.maximum(out_ref[...],
-                                       jnp.max(cand, axis=0))
-        elif op == "or":
-            hit = jnp.any(onehot & (val[:, None] != 0), axis=0)
-            out_ref[...] = jnp.maximum(out_ref[...],
-                                       hit.astype(out_ref.dtype))
-        elif op == "first":
-            # first-writer-wins into empty (<0) slots; tie-break =
-            # lowest GLOBAL message id (m * tile_m + row) — transactions
-            # execute in grid order, so the in-tile winner composes to
-            # the batch-wide lowest id, exactly like the coarse kernel.
-            cur = out_ref[...]
-            empty = cur < 0
-            rank = (m * tile_m
-                    + jax.lax.broadcasted_iota(jnp.int32,
-                                               (tile_m, block_v), 0))
-            rkey = jnp.where(onehot & empty[None, :], rank, _RANK_INF)
-            win = jnp.min(rkey, axis=0)                  # [B]
-            wsel = (onehot & (rkey == win[None, :])
-                    & (win[None, :] < _RANK_INF))
-            wval = jnp.sum(jnp.where(wsel, val[:, None], 0), axis=0)
-            out_ref[...] = jnp.where(empty & (win < _RANK_INF),
-                                     wval.astype(cur.dtype), cur)
+        commit_window(out_ref, conf_ref, jnp.where(mask, kk, 0), mask,
+                      val_ref[...].T, op=op, first_id=w * idx.shape[0],
+                      tile_m=tile_m, block_v=block_v)
 
 
 @functools.partial(jax.jit, static_argnames=("op", "width", "tile_m",
@@ -155,7 +110,7 @@ def _fused_kernel(*refs, op: str, tile_m: int, block_v: int, width: int,
 def fused_route_commit_pallas(state, tgt, val, *, lane=None, base=None,
                               width: int = 1, op: str = "min",
                               tile_m: int = 256, block_v: int = 512,
-                              interpret: bool = True, stats: bool = False):
+                              interpret: bool, stats: bool = False):
     """One launch from exchanged bucket buffers to committed state.
 
     state: [R * width] local composite-key slice (R vertex rows × width
@@ -168,56 +123,54 @@ def fused_route_commit_pallas(state, tgt, val, *, lane=None, base=None,
 
     Returns the committed state; ``stats=True`` returns
     ``(state, conflicts)`` with the grid-summed duplicate-target count.
-    ``interpret=True`` executes on CPU; pass ``False`` on real TPU.
+    ``interpret`` has no default: :func:`repro.kernels.interpret_default`
+    decides it for the running backend.
     """
     if (lane is None) == (width > 1):
         raise ValueError(f"lane ids are required iff width > 1 "
                          f"(width={width}, lane={'set' if lane is not None else 'None'})")
+    if op not in COMMIT_OPS:
+        raise ValueError(op)
+    check_block(block_v, interpret)
     v = state.shape[0]
     n = tgt.shape[0]
     if v % width:
         raise ValueError(f"state length {v} not divisible by width {width}")
     if n == 0 or v == 0:
         return (state, jnp.zeros((), jnp.int32)) if stats else state
-    nrows = v // width
-    vpad = (-v) % block_v
-    npad = (-n) % tile_m
-    ident = _identity(op, state.dtype)
-    state_p = jnp.pad(state, (0, vpad),
-                      constant_values=state.dtype.type(ident)
-                      if op not in ("add", "or") else 0)
-    tgt_p = jnp.pad(tgt.astype(jnp.int32), (0, npad), constant_values=-1)
-    val_p = jnp.pad(val, (0, npad))
-    nb = (v + vpad) // block_v
-    nm = (n + npad) // tile_m
+    window = message_window(tile_m)
+    state_p = pad_state_row(state, op, block_v)
 
-    tile_spec = pl.BlockSpec((tile_m,), lambda b, m: (m,))
-    in_specs = [tile_spec, tile_spec,
-                pl.BlockSpec((block_v,), lambda b, m: (b,))]
-    inputs = [tgt_p, val_p, state_p]
+    row_spec = pl.BlockSpec((1, block_v), lambda b, w: (0, b))
+    msg_spec = pl.BlockSpec((1, window), lambda b, w: (0, w))
+    in_specs = [msg_spec, msg_spec, row_spec]
+    inputs = [pad_message_row(tgt.astype(jnp.int32), window, -1),
+              pad_message_row(val, window), state_p]
     if lane is not None:
-        in_specs.append(tile_spec)
-        inputs.append(jnp.pad(lane.astype(jnp.int32), (0, npad)))
+        in_specs.append(msg_spec)
+        inputs.append(pad_message_row(lane.astype(jnp.int32), window))
     if base is not None:
-        in_specs.append(pl.BlockSpec((1,), lambda b, m: (0,)))
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         inputs.append(jnp.reshape(jnp.asarray(base, jnp.int32), (1,)))
-    out_specs = [pl.BlockSpec((block_v,), lambda b, m: (b,))]
+    out_specs = [row_spec]
     out_shape = [jax.ShapeDtypeStruct(state_p.shape, state.dtype)]
     if stats:
-        out_specs.append(pl.BlockSpec((1, 1), lambda b, m: (b, m)))
-        out_shape.append(jax.ShapeDtypeStruct((nb, nm), jnp.int32))
+        out_specs.append(row_spec)
+        out_shape.append(jax.ShapeDtypeStruct(state_p.shape, jnp.int32))
     res = pl.pallas_call(
         functools.partial(_fused_kernel, op=op, tile_m=tile_m,
-                          block_v=block_v, width=width, nrows=nrows,
+                          block_v=block_v, width=width, nrows=v // width,
                           with_lane=lane is not None,
                           with_base=base is not None, stats=stats),
-        grid=(nb, nm),
+        grid=commit_grid(v, n, tile_m, block_v),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit(window, block_v)),
     )(*inputs)
     if stats:
         out, conf = res
-        return out[:v], jnp.sum(conf)
-    return res[0][:v]
+        return out[0, :v], jnp.sum(conf)
+    return res[0][0, :v]

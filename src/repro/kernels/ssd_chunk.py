@@ -33,7 +33,7 @@ def _ssd_kernel(C_ref, B_ref, x_ref, a_ref, out_ref, *, L: int):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_chunk_pallas(C, B, x, a, *, interpret: bool = True):
+def ssd_chunk_pallas(C, B, x, a, *, interpret: bool):
     """C, B: [G, L, N]; x: [G, L, P]; a: [G, L] log-decays.
 
     G = batch·chunks·heads flattened grid dim. Returns y [G, L, P]."""

@@ -7,6 +7,15 @@ dry-run needs to set XLA_FLAGS first).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple, axes: tuple):
+    """``jax.make_mesh`` with ``Auto`` axes.  Its default (``Explicit``)
+    types every array by its sharding, so slicing or gathering a
+    ``shard_map`` result outside the map raises ``ShardingTypeError``;
+    the engine's round loops and their callers rely on ``Auto``."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,11 +23,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     axis (2 pods = 512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1, pod: int | None = None):
     """Small mesh over host devices (tests / CPU-distributed runs)."""
     if pod is not None:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _auto_mesh((pod, data, model), ("pod", "data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))

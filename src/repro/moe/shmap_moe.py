@@ -24,7 +24,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -108,7 +107,7 @@ def moe_apply_shmap(cfg: ModelConfig, p, x2d):
 
     dspec = daxes if len(daxes) > 1 else (daxes[0] if daxes else None)
     if has_gate:
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             inner, mesh=mesh,
             in_specs=(P("data", "model"),             # router [d, E]
                       P("model", "data", None),       # wi [E, d, f]
@@ -122,7 +121,7 @@ def moe_apply_shmap(cfg: ModelConfig, p, x2d):
     else:
         def inner4(router, wi, wo, x):
             return inner(router, wi, None, wo, x)
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             inner4, mesh=mesh,
             in_specs=(P("data", "model"), P("model", "data", None),
                       P("model", None, "data"), P(dspec, None)),

@@ -18,7 +18,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from jax.sharding import PartitionSpec as Ps
 
 
@@ -70,7 +69,7 @@ def make_compressed_dp_step(loss_fn, opt, mesh, axis: str = "pod"):
             new_p, new_o = opt.update(g, opt_state, params, step_i)
             l = jax.lax.pmean(l, axis)
             return new_p, new_o, ef2, l
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(Ps(), Ps(), Ps(), Ps(), Ps(axis)),
             out_specs=(Ps(), Ps(), Ps(), Ps()),

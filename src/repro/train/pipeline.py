@@ -19,7 +19,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from jax.sharding import PartitionSpec as Ps
 
 from repro.configs.base import ModelConfig, RunConfig
@@ -92,7 +91,7 @@ def pipeline_forward(cfg: ModelConfig, rcfg: RunConfig, mesh, axis: str,
         logits = jax.lax.psum(logits, axis)
         return logits
 
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(Ps(), Ps()),
         out_specs=Ps(), check_vma=False))

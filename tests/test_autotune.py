@@ -302,10 +302,10 @@ def test_pallas_commit_nostats_skips_conflict_reduction():
     idx = jnp.asarray([1, 1, 2, 3, 3, 3, -1, -1], jnp.int32)
     val = jnp.ones((8,), jnp.int32)
     out = coarse_commit_pallas(state, idx, val, op="add", tile_m=8,
-                               block_v=16, stats=False)
+                               block_v=16, stats=False, interpret=True)
     assert isinstance(out, jnp.ndarray)          # single output, no tuple
     ref, conf = coarse_commit_pallas(state, idx, val, op="add", tile_m=8,
-                                     block_v=16, stats=True)
+                                     block_v=16, stats=True, interpret=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
     assert int(conf) == 5
     # the commit() wrapper: stats=False reports zero conflicts (cheap path)

@@ -395,7 +395,6 @@ def test_distributed_conflicts_match_single_shard_counts():
     r = run_devices("""
         import json, numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as Ps
-        from repro import compat
         from repro.launch.mesh import make_host_mesh
         from repro.core import commit as C
         from repro.core.engine import EngineConfig, wave_until_delivered
@@ -427,7 +426,7 @@ def test_distributed_conflicts_match_single_shard_counts():
                     jnp.ones((n // P,), bool))
                 return st2, cf, dall
 
-            fn = compat.shard_map(
+            fn = jax.shard_map(
                 shard_fn, mesh=mesh,
                 in_specs=(Ps("data"), Ps("data"), Ps("data")),
                 out_specs=(Ps("data"), Ps(), Ps()), check_vma=False)
@@ -455,7 +454,7 @@ def test_distributed_conflicts_match_single_shard_counts():
                 {"a": pl[0], "b": pl[0]}, jnp.ones((n // P,), bool))
             return st2["a"], cf
 
-        fn2 = compat.shard_map(
+        fn2 = jax.shard_map(
             shard2, mesh=mesh,
             in_specs=(Ps("data"), Ps("data"), Ps("data")),
             out_specs=(Ps("data"), Ps()), check_vma=False)
@@ -485,7 +484,6 @@ def test_wave_surfaces_wedge_instead_of_silent_drop():
     r = run_devices("""
         import json, numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as Ps
-        from repro import compat
         from repro.launch.mesh import make_host_mesh
         from repro.core.engine import EngineConfig, wave_until_delivered
         mesh = make_host_mesh(2, 1)
@@ -504,10 +502,10 @@ def test_wave_surfaces_wedge_instead_of_silent_drop():
                     max_subrounds=msr)
                 return st2, sr, dall
 
-            fn = compat.shard_map(shard_fn, mesh=mesh,
-                                  in_specs=(Ps("data"),),
-                                  out_specs=(Ps("data"), Ps(), Ps()),
-                                  check_vma=False)
+            fn = jax.shard_map(shard_fn, mesh=mesh,
+                               in_specs=(Ps("data"),),
+                               out_specs=(Ps("data"), Ps(), Ps()),
+                               check_vma=False)
             st2, sr, dall = jax.jit(fn)(
                 jnp.full((V,), 2**20, jnp.int32))
             out[name] = {"delivered_all": bool(dall), "subrounds": int(sr),

@@ -189,10 +189,11 @@ def test_lane_width_contract():
     st0 = jnp.zeros((8,), jnp.int32)
     tgt = jnp.zeros((4,), jnp.int32)
     with pytest.raises(ValueError, match="lane ids"):
-        fused_route_commit_pallas(st0, tgt, tgt, width=2, op="add")
+        fused_route_commit_pallas(st0, tgt, tgt, width=2, op="add",
+                                  interpret=True)
     with pytest.raises(ValueError, match="lane ids"):
         fused_route_commit_pallas(st0, tgt, tgt, lane=tgt, width=1,
-                                  op="add")
+                                  op="add", interpret=True)
 
 
 def test_ladder_fused_site_matches_static():
@@ -356,6 +357,45 @@ def test_auto_can_select_fused(monkeypatch):
                                             stats=False))
     np.testing.assert_array_equal(np.asarray(rf.state),
                                   np.asarray(rc.state))
+
+
+def test_auto_keeps_kernel_tiers_off_huge_grids(monkeypatch):
+    """Even when the calibration ranks fused fastest, a commit whose
+    kernel grid (state blocks x message tiles) exceeds
+    KERNEL_MAX_GRID_STEPS resolves to a jnp tier, and its ladder keeps
+    only transaction sizes that split the batch into at most
+    MAX_TRANSACTIONS transactions."""
+    monkeypatch.setenv("REPRO_AUTOTUNE", "on")
+    monkeypatch.setenv(AT._ALLOW_INTERP_ENV, "1")
+    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", "off")
+    fit = perf_model.LinearFit
+    cal = AT.Calibration(
+        fine=fit(intercept=0.0, slope=1e-6, r2=1.0),
+        tiers=(("atomic", fit(intercept=1e-3, slope=1e-6, r2=1.0)),
+               ("coarse", fit(intercept=1e-3, slope=1e-6, r2=1.0)),
+               ("pallas", fit(intercept=9e-4, slope=1e-6, r2=1.0)),
+               ("fused", fit(intercept=1e-5, slope=1e-8, r2=1.0))))
+    jnp_only = AT.Calibration(fine=cal.fine, tiers=tuple(
+        (b, f) for b, f in cal.tiers if b not in AT.KERNEL_BACKENDS))
+    monkeypatch.setattr(AT.AutoTuner, "calibrate",
+                        lambda self, with_pallas, **kw:
+                        cal if with_pallas else jnp_only)
+    rank = ("fused", "coarse", "pallas", "atomic")
+    monkeypatch.setattr(AT.AutoTuner, "race",
+                        lambda self, finalists, n, **kw:
+                        min(finalists, key=rank.index))
+    spec = CommitSpec(backend="auto", stats=False, interpret=True)
+    state = jax.ShapeDtypeStruct((1 << 22,), jnp.int32)    # 8192 blocks
+    tuner = _small_tuner()
+    n = 1 << 25
+    pol = AT.policy_for(spec, state, n=n, op="min", tuner=tuner)
+    assert pol.backend == "coarse"
+    assert pol.ladder == AT.feasible_ladder(n) == (None,)
+    assert any(e.get("event") == "kernel_tiers_excluded"
+               and "grid" in e["reason"] for e in tuner.audit)
+    small = AT.policy_for(spec, jnp.zeros((96,), jnp.int32), n=96,
+                          op="min", tuner=_small_tuner())
+    assert small.backend == "fused" and small.ladder == AT.M_LADDER
 
 
 # -- satellite: the pallas bucket-count path --------------------------------

@@ -49,14 +49,16 @@ def test_coarse_commit_stats_output():
     idx = jnp.asarray([1, 1, 2, 3, 3, 3, -1, -1], jnp.int32)
     val = jnp.ones((8,), jnp.int32)
     out, conf = coarse_commit_pallas(state, idx, val, op="add", tile_m=8,
-                                     block_v=16, stats=True)
+                                     block_v=16, stats=True,
+                                     interpret=True)
     assert int(conf) == 5  # 2 on vertex 1 + 3 on vertex 3
     np.testing.assert_array_equal(
         np.asarray(out), np.asarray(coarse_commit_ref(state, idx, val,
                                                       op="add")))
     # two transactions of 4: the duplicate pair on vertex 3 splits 2|1
     _, conf2 = coarse_commit_pallas(state, idx, val, op="add", tile_m=4,
-                                    block_v=16, stats=True)
+                                    block_v=16, stats=True,
+                                    interpret=True)
     assert int(conf2) == 4
 
 

@@ -33,19 +33,48 @@ def _axes(draw):
     return ProductAxis(lanes, sizes)
 
 
+CELLS = 256     # >= 5 lanes x 5 graphs x 9 vertices, the largest _axes
+
+
+def _key_ops(axis, cells):
+    """flatten3 then split3 over a [CELLS, 3] (lane, graph, v) block."""
+    keys = axis.flatten3(*(jnp.asarray(c) for c in cells.T))
+    back = np.stack([np.asarray(x) for x in axis.split3(keys)], axis=1)
+    return np.asarray(keys), back
+
+
+@pytest.fixture(scope="module")
+def key_ops_compiled():
+    """Compile the key ops for every graph count the strategies draw
+    (their shapes depend on G only) before the properties run; otherwise
+    the first examples pay the compiles inside Hypothesis's 200 ms
+    deadline."""
+    for g in range(1, 6):
+        sizes = (3,) * g
+        axis = ProductAxis(2, sizes)
+        _key_ops(axis, np.zeros((CELLS, 3), np.int32))
+        axis.split3(int(axis.flatten3(1, g - 1, 2)))
+        int(GraphBatch(sizes).flatten(g - 1, 2))
+    keys = jnp.zeros((16,), jnp.int32)
+    axis.unflatten(axis.flatten(keys, keys))
+
+
+@pytest.mark.usefixtures("key_ops_compiled")
 @settings(max_examples=40)
 @given(_axes(), st.integers(0, 2 ** 31 - 1))
 def test_product_flat_keys_bijective(axis, seed):
     """flatten3 over every (lane, graph, v) cell-vertex hits each key in
     [0, flat_size) exactly once, and split3 inverts it — including the
-    L=1 and G=1 degenerate shapes."""
-    keys = []
-    for lane in range(axis.lanes):
-        for g, sz in enumerate(axis.sizes):
-            for v in range(sz):
-                k = int(axis.flatten3(lane, g, v))
-                assert axis.split3(k) == (lane, g, v)
-                keys.append(k)
+    L=1 and G=1 degenerate shapes.  The cells go through in one
+    fixed-size block (padded with copies of the first cell)."""
+    cells = np.asarray([(lane, g, v) for lane in range(axis.lanes)
+                        for g, sz in enumerate(axis.sizes)
+                        for v in range(sz)], np.int32)
+    padded = np.concatenate([cells, np.repeat(cells[:1], CELLS - len(cells),
+                                              axis=0)])
+    keys, back = _key_ops(axis, padded)
+    np.testing.assert_array_equal(back, padded)
+    keys = keys[:len(cells)].tolist()
     assert sorted(keys) == list(range(axis.flat_size))
     # the two-level protocol agrees with the three-level helper
     rng = np.random.default_rng(seed)
@@ -56,6 +85,7 @@ def test_product_flat_keys_bijective(axis, seed):
     np.testing.assert_array_equal(np.asarray(minor2), np.asarray(minor))
 
 
+@pytest.mark.usefixtures("key_ops_compiled")
 @settings(max_examples=25)
 @given(st.lists(st.integers(1, 9), min_size=1, max_size=5))
 def test_product_of_one_lane_is_graph_batch(sizes):

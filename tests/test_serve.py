@@ -685,7 +685,7 @@ def test_autotune_cache_keys_include_device_kind(tmp_path, monkeypatch):
                 interpret=None, with_pallas=False)
     import jax
     doc = json.loads((tmp_path / "c.json").read_text())
-    assert all(k.split("|")[1].startswith(jax.default_backend())
+    assert all(k.split("|")[1] == jax.devices()[0].device_kind
                for k in doc["entries"])
 
 
@@ -723,19 +723,23 @@ def test_capacity_auto_grows_on_persistent_overflow():
 
 def test_capacity_auto_heuristic_bounds():
     from repro.core import engine as E
+    from repro.graphs.csr import partition_edges
     g = kronecker(6, 4, seed=1)
     for p in (1, 2, 8):
-        c = E.auto_capacity(g, p)
-        assert E.CAPACITY_MIN <= c <= E.CAPACITY_MAX
+        emax = partition_edges(g, p)[0][0].shape[1]
+        c = E.auto_capacity(g, p, emax)
+        assert E.CAPACITY_MIN <= c <= E.capacity_cap(emax)
         assert c & (c - 1) == 0              # power of two
     # quiet runs leave the cache alone; overflowing runs double it
     key = (g.num_vertices, g.num_edges, 2)
     old = E._CAPACITY_CACHE.pop(key, None)
     try:
-        E._capacity_feedback(g, 2, 256, subrounds=10, rounds=10)
+        E._capacity_feedback(g, 2, 256, subrounds=10, rounds=10, cap=1024)
         assert key not in E._CAPACITY_CACHE
-        E._capacity_feedback(g, 2, 256, subrounds=50, rounds=10)
+        E._capacity_feedback(g, 2, 256, subrounds=50, rounds=10, cap=1024)
         assert E._CAPACITY_CACHE[key] == 512
+        E._capacity_feedback(g, 2, 512, subrounds=50, rounds=10, cap=512)
+        assert E._CAPACITY_CACHE[key] == 512     # held at the cap
     finally:
         E._CAPACITY_CACHE.pop(key, None)
         if old is not None:
