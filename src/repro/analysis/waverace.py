@@ -13,9 +13,11 @@ it:
 * the *state chain* starts at the round's state-leaf inputs and grows
   through aliasing primitives (reshape/convert/select/...) and through
   scatter outputs (a functional scatter's result aliases its operand);
-* every ``commit()`` executes under ``jax.named_scope("aam_commit")``,
-  which JAX records in each equation's ``source_info.name_stack`` —
-  including inside ``while``/``scan`` sub-jaxprs;
+* every ``commit()`` executes under ``jax.named_scope("aam_commit")``
+  (``repro.core.commit.COMMIT_SCOPE``), which JAX records in each
+  equation's ``source_info.name_stack`` — including inside
+  ``while``/``scan`` sub-jaxprs — and an equation is inside when one
+  component of that path is the scope's name;
 * a scatter whose operand is on the chain **without** ``aam_commit`` on
   its name stack is a finding: a raw state write that bypasses conflict
   resolution.  Gathers of chained arrays outside the scope are recorded
@@ -42,6 +44,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import os
+import re
 from types import SimpleNamespace
 
 import numpy as np
@@ -51,7 +54,7 @@ from jax.extend.core import Literal
 import jax.numpy as jnp
 
 from repro.core import engine as E
-from repro.core.commit import CommitSpec, commit
+from repro.core.commit import COMMIT_SCOPE, CommitSpec, commit
 from repro.core.coalescing import fuse_keys
 from repro.core.messages import make_messages
 
@@ -77,7 +80,7 @@ KERNEL_PRIMS = {"pallas_call"}
 # state reads
 GATHER_PRIMS = {"gather", "dynamic_slice"}
 
-_SCOPE = "aam_commit"
+_SCOPE = COMMIT_SCOPE
 
 
 @dataclasses.dataclass
@@ -100,8 +103,16 @@ class RaceReport:
         return not self.findings
 
 
+def scope_components(name_stack) -> list:
+    """The scope names of a name stack (``"vmap(aam_commit)/x"`` ->
+    ``["vmap", "aam_commit", "x"]``): path components with transform
+    wrappers split off, so a scope matches by its whole name and never
+    as a prefix of a longer one (``aam_commit_stats``)."""
+    return [c for c in re.split(r"[/()]", str(name_stack)) if c]
+
+
 def _in_scope(eqn) -> bool:
-    return _SCOPE in str(eqn.source_info.name_stack)
+    return _SCOPE in scope_components(eqn.source_info.name_stack)
 
 
 def _vars(atoms):

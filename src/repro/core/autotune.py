@@ -42,9 +42,11 @@ the spec for bit-reproducible mechanism choice across hosts — final
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +56,7 @@ from repro.core import perf_model
 from repro.core.commit import AUTO, BACKENDS, CommitSpec, CommitResult, \
     _pallas_supported, commit
 from repro.core.messages import Messages, make_messages
+from repro.obs import trace as OT
 
 # Power-of-two transaction-size ladder (None = whole batch, the M -> inf
 # column of paper Fig 4).  Chosen to bracket the kernel's VMEM-capacity
@@ -193,6 +196,10 @@ class AutoTuner:
         # timed micro-benchmark invocations this process — a restored
         # warm service asserts this stays flat (zero recalibration)
         self.timed_runs = 0
+        # wall seconds inside calibrations and races that ran (the
+        # ``aam.tune`` spans), compiles of the micro-commits included;
+        # a cache hit adds nothing
+        self.tune_s = 0.0
         # decision audit log: every calibration fit, finalist race, and
         # policy verdict, with the measurements that justified it
         # (bounded FIFO; ladder moves stream via repro.obs.wavetap)
@@ -268,8 +275,18 @@ class AutoTuner:
 
     # -- measurement ------------------------------------------------------
 
+    @contextlib.contextmanager
+    def _tuning(self, what: str):
+        """One calibration or race that runs: an ``aam.tune`` span, its
+        wall time added to :attr:`tune_s`."""
+        t0 = time.perf_counter()
+        try:
+            with OT.span("tune", cat="tune", args={"what": what}):
+                yield
+        finally:
+            self.tune_s += time.perf_counter() - t0
+
     def _time(self, fn, *args) -> float:
-        import time
         self.timed_runs += 1
         for _ in range(self.warmup):
             jax.block_until_ready(fn(*args))
@@ -354,25 +371,26 @@ class AutoTuner:
             except (KeyError, TypeError, ValueError):
                 pass
         wl = dict(op=op, dtype=dtype, width=width)
-        # fine tier: ONE message per activity => T_fine(N) = N * t_unit
-        state, msgs1 = self._workload(1, **wl)
-        spec_f = CommitSpec(backend="atomic", stats=stats)
-        t_unit = self._time(
-            jax.jit(lambda s, m: commit(s, m, op, spec_f).state),
-            state, msgs1)
-        fine = perf_model.LinearFit(intercept=0.0, slope=t_unit, r2=1.0)
-        tiers = []
-        backends = [b for b in BACKENDS
-                    if with_pallas or b not in KERNEL_BACKENDS]
-        for b in backends:
-            spec = CommitSpec(backend=b, m=None, sort=sort, stats=stats,
-                              tile_m=tile_m, block_v=block_v,
-                              interpret=interpret)
-            fn = jax.jit(lambda s, m, spec=spec:
-                         commit(s, m, op, spec).state)
-            times = [self._time(fn, *self._workload(n, **wl))
-                     for n in self.ns]
-            tiers.append((b, _sanitize(perf_model.fit(self.ns, times))))
+        with self._tuning("calibrate"):
+            # fine tier: ONE message per activity => T_fine(N) = N * t_unit
+            state, msgs1 = self._workload(1, **wl)
+            spec_f = CommitSpec(backend="atomic", stats=stats)
+            t_unit = self._time(
+                jax.jit(lambda s, m: commit(s, m, op, spec_f).state),
+                state, msgs1)
+            fine = perf_model.LinearFit(intercept=0.0, slope=t_unit, r2=1.0)
+            tiers = []
+            backends = [b for b in BACKENDS
+                        if with_pallas or b not in KERNEL_BACKENDS]
+            for b in backends:
+                spec = CommitSpec(backend=b, m=None, sort=sort, stats=stats,
+                                  tile_m=tile_m, block_v=block_v,
+                                  interpret=interpret)
+                fn = jax.jit(lambda s, m, spec=spec:
+                             commit(s, m, op, spec).state)
+                times = [self._time(fn, *self._workload(n, **wl))
+                         for n in self.ns]
+                tiers.append((b, _sanitize(perf_model.fit(self.ns, times))))
         cal = Calibration(fine=fine, tiers=tuple(tiers))
         self._cache[key] = cal
         self._disk_put(dkey, {
@@ -430,15 +448,16 @@ class AutoTuner:
             self._cache[key] = disk
             return disk
         times = {}
-        for b, m in finalists.items():
-            spec = CommitSpec(backend=b, m=m, sort=sort, stats=stats,
-                              tile_m=tile_m, block_v=block_v,
-                              interpret=interpret)
-            fn = jax.jit(lambda s, msgs, spec=spec:
-                         commit(s, msgs, op, spec).state)
-            times[b] = self._time(fn, *self._workload(
-                n, v, op=op, dtype=dtype, width=width,
-                axis_width=axis_width))
+        with self._tuning("race"):
+            for b, m in finalists.items():
+                spec = CommitSpec(backend=b, m=m, sort=sort, stats=stats,
+                                  tile_m=tile_m, block_v=block_v,
+                                  interpret=interpret)
+                fn = jax.jit(lambda s, msgs, spec=spec:
+                             commit(s, msgs, op, spec).state)
+                times[b] = self._time(fn, *self._workload(
+                    n, v, op=op, dtype=dtype, width=width,
+                    axis_width=axis_width))
         winner = min(times, key=times.get)
         self._cache[key] = winner
         self._disk_put(dkey, winner)

@@ -42,10 +42,24 @@ import jax.numpy as jnp
 
 from repro.core.messages import Messages, make_messages
 from repro.kernels import interpret_default
+from repro.obs.trace import key_compile_cache_on_metadata
 
 OPS = ("min", "max", "add", "or", "first")
 BACKENDS = ("atomic", "coarse", "pallas", "fused")
 AUTO = "auto"   # CommitSpec(backend="auto"): online-calibrated backend + M
+
+# ``jax.named_scope`` names of a round's phases.  They survive into the
+# optimized HLO as path components of each instruction's ``op_name``, which
+# names the phase of every device op in a profiler trace;
+# ``repro.analysis.waverace`` keys its in-wave-race rule on COMMIT_SCOPE.
+COMMIT_SCOPE = "aam_commit"            # the conflict-resolved write path
+STATS_SCOPE = "aam_commit_stats"       # success/conflict/applied, nested
+MESSAGES_SCOPE = "aam_messages"        # building a round's messages
+PLAN_SCOPE = "aam_plan"                # coalescing bucket plan
+EXCHANGE_SCOPE = "aam_exchange"        # the all-to-all between shards
+# the scopes live only in metadata: a cached executable must carry this
+# code's, not another version's
+key_compile_cache_on_metadata()
 
 
 def _identity(op: str, dtype):
@@ -174,7 +188,7 @@ def commit(state: jax.Array, msgs: Messages, op: str,
     # write path in traced jaxprs — repro.analysis.waverace keys its
     # in-wave-race rule on it (raw state writes OUTSIDE this scope are
     # unserialized and get flagged)
-    with jax.named_scope("aam_commit"):
+    with jax.named_scope(COMMIT_SCOPE):
         res = _dispatch(state, msgs, op, spec, backend)
         if (spec.sanitize or _sanitize_env()) and msgs.capacity > 1:
             from repro.analysis.sanitize import shadow_check  # lazy: no cycle
@@ -344,7 +358,7 @@ def fused_commit_site(state, tgt, payload, op: str, spec: CommitSpec, *,
     local keys jnp-side ONLY for the MF success/applied accounting (the
     committed state still comes from the single launch).
 
-    Runs under ``jax.named_scope("aam_commit")`` — the aamlint waverace
+    Runs under ``jax.named_scope(COMMIT_SCOPE)`` — the aamlint waverace
     pass recognizes in-scope ``pallas_call`` writes as the protected
     commit site and flags out-of-scope kernel writes.
     """
@@ -353,7 +367,7 @@ def fused_commit_site(state, tgt, payload, op: str, spec: CommitSpec, *,
     kw = dict(lane=lane, base=base, width=width, op=op, tile_m=tile_m,
               block_v=spec.block_v, interpret=interpret)
     from repro.kernels.fused_wave import fused_route_commit_pallas
-    with jax.named_scope("aam_commit"):
+    with jax.named_scope(COMMIT_SCOPE):
         if not spec.stats:
             new = fused_route_commit_pallas(state, tgt, payload,
                                             stats=False, **kw)
@@ -361,18 +375,19 @@ def fused_commit_site(state, tgt, payload, op: str, spec: CommitSpec, *,
             return CommitResult(new, tgt >= 0, z, z)
         new, conflicts = fused_route_commit_pallas(state, tgt, payload,
                                                    stats=True, **kw)
-        nrows = state.shape[0] // width
-        rel = tgt - (0 if base is None else base)
-        ok = (tgt >= 0) & (rel >= 0) & (rel < nrows)   # mirror the kernel
-        local = jnp.where(ok, rel, 0)
-        if width > 1:
-            ok = ok & (lane >= 0) & (lane < width)
-            local = local * width + jnp.where(ok, lane, 0)
-        msgs = make_messages(local.astype(jnp.int32), payload, ok)
-        if op == "first":
-            success, _, applied = _first_stats(state, msgs)
-        else:
-            success, _, applied = _success_stats(state, new, msgs, op)
+        with jax.named_scope(STATS_SCOPE):
+            nrows = state.shape[0] // width
+            rel = tgt - (0 if base is None else base)
+            ok = (tgt >= 0) & (rel >= 0) & (rel < nrows)  # mirror the kernel
+            local = jnp.where(ok, rel, 0)
+            if width > 1:
+                ok = ok & (lane >= 0) & (lane < width)
+                local = local * width + jnp.where(ok, lane, 0)
+            msgs = make_messages(local.astype(jnp.int32), payload, ok)
+            if op == "first":
+                success, _, applied = _first_stats(state, msgs)
+            else:
+                success, _, applied = _success_stats(state, new, msgs, op)
         return CommitResult(new, success, conflicts, applied)
 
 
@@ -452,8 +467,10 @@ def coarse_commit(state: jax.Array, msgs: Messages, op: str,
         return r.state, (r.success, r.conflicts, r.applied)
 
     new_state, (succ, conf, app) = jax.lax.scan(tx, state, tiles)
-    succ = succ.reshape(-1)[:n]
-    return CommitResult(new_state, succ, jnp.sum(conf), jnp.sum(app))
+    with jax.named_scope(STATS_SCOPE):
+        succ = succ.reshape(-1)[:n]
+        conf, app = jnp.sum(conf), jnp.sum(app)
+    return CommitResult(new_state, succ, conf, app)
 
 
 def _resolved_commit(state, msgs: Messages, op: str, sort: bool,
@@ -516,16 +533,17 @@ def _resolved_commit(state, msgs: Messages, op: str, sort: bool,
         new = state.at[w_idx].max(scanned.astype(state.dtype), mode=mode)
     if stats:
         success, conflicts, applied = _success_stats(old, new, msgs, op)
-    else:
+        return CommitResult(new, success, conflicts, applied)
+    with jax.named_scope(STATS_SCOPE):
         n_valid = jnp.sum(s_valid.astype(jnp.int32))
         n_runs = jnp.sum((first & s_valid).astype(jnp.int32))
         conflicts = n_valid - n_runs
-        changed = new[jnp.clip(s_idx, 0, v - 1)] != old[jnp.clip(s_idx, 0, v - 1)]
+        changed = (new[jnp.clip(s_idx, 0, v - 1)]
+                   != old[jnp.clip(s_idx, 0, v - 1)])
         if changed.ndim > 1:    # vector payload: any component changed
             changed = jnp.any(changed, axis=tuple(range(1, changed.ndim)))
         applied = jnp.sum((last & s_valid & changed).astype(jnp.int32))
-        success = msgs.valid
-    return CommitResult(new, success, conflicts, applied)
+    return CommitResult(new, msgs.valid, conflicts, applied)
 
 
 def _first_winner(state, msgs: Messages, rank=None):
@@ -548,14 +566,16 @@ def _first_winner(state, msgs: Messages, rank=None):
 def _first_stats(state, msgs: Messages):
     """(success, conflicts, applied) of a whole-batch 'first' commit
     against the pre-commit ``state``."""
-    v = state.shape[0]
-    winner_rank, takes = _first_winner(state, msgs)
-    tgt = jnp.clip(msgs.target, 0, v - 1)
-    msg_rank = jnp.arange(msgs.capacity, dtype=jnp.int32)
-    success = msgs.valid & (msg_rank == winner_rank[tgt]) & (state < 0)[tgt]
-    conflicts = jnp.sum(msgs.valid) - jnp.sum(takes)
-    return success, conflicts.astype(jnp.int32), \
-        jnp.sum(takes).astype(jnp.int32)
+    with jax.named_scope(STATS_SCOPE):
+        v = state.shape[0]
+        winner_rank, takes = _first_winner(state, msgs)
+        tgt = jnp.clip(msgs.target, 0, v - 1)
+        msg_rank = jnp.arange(msgs.capacity, dtype=jnp.int32)
+        success = (msgs.valid & (msg_rank == winner_rank[tgt])
+                   & (state < 0)[tgt])
+        conflicts = jnp.sum(msgs.valid) - jnp.sum(takes)
+        return success, conflicts.astype(jnp.int32), \
+            jnp.sum(takes).astype(jnp.int32)
 
 
 def _first_commit(state, msgs: Messages) -> CommitResult:
@@ -571,29 +591,35 @@ def _first_commit(state, msgs: Messages) -> CommitResult:
 
 
 def _success_stats(old, new, msgs: Messages, op: str):
-    n = msgs.capacity
-    v = old.shape[0]
-    tgt = jnp.clip(msgs.target, 0, v - 1)
-    if op == "add":
-        success = msgs.valid
-        applied = jnp.sum(msgs.valid)
-    elif op == "or":
-        success = msgs.valid & ~old[tgt].astype(bool)
-        applied = jnp.sum((new != old).astype(jnp.int32))
-    else:  # min/max — MF: message wins iff it set the final value
-        val = msgs.payload
-        final = new[tgt]
-        improved = (val == final) & (final != old[tgt]) & msgs.valid
-        # first among equal winners
-        msg_rank = jnp.arange(n, dtype=jnp.int32)
-        rank_key = jnp.where(improved, msg_rank, n)
-        idx = jnp.where(improved, msgs.target, v)
-        first_rank = jax.ops.segment_min(rank_key, idx, num_segments=v + 1)[:v]
-        success = improved & (msg_rank == first_rank[tgt])
-        applied = jnp.sum((new != old).astype(jnp.int32))
-    # conflicts = valid messages sharing a target with another message
-    idx = jnp.where(msgs.valid, msgs.target, v)
-    counts = jax.ops.segment_sum(jnp.ones((n,), jnp.int32), idx,
-                                 num_segments=v + 1)[:v]
-    conflicts = jnp.sum(jnp.where(msgs.valid & (counts[tgt] > 1), 1, 0))
-    return success, conflicts.astype(jnp.int32), applied.astype(jnp.int32)
+    """(success, conflicts, applied) of a commit of ``msgs`` that took
+    ``old`` to ``new``: MF success flags and the telemetry counters."""
+    with jax.named_scope(STATS_SCOPE):
+        n = msgs.capacity
+        v = old.shape[0]
+        tgt = jnp.clip(msgs.target, 0, v - 1)
+        if op == "add":
+            success = msgs.valid
+            applied = jnp.sum(msgs.valid)
+        elif op == "or":
+            success = msgs.valid & ~old[tgt].astype(bool)
+            applied = jnp.sum((new != old).astype(jnp.int32))
+        else:  # min/max — MF: message wins iff it set the final value
+            val = msgs.payload
+            final = new[tgt]
+            improved = (val == final) & (final != old[tgt]) & msgs.valid
+            # first among equal winners
+            msg_rank = jnp.arange(n, dtype=jnp.int32)
+            rank_key = jnp.where(improved, msg_rank, n)
+            idx = jnp.where(improved, msgs.target, v)
+            first_rank = jax.ops.segment_min(rank_key, idx,
+                                             num_segments=v + 1)[:v]
+            success = improved & (msg_rank == first_rank[tgt])
+            applied = jnp.sum((new != old).astype(jnp.int32))
+        # conflicts = valid messages sharing a target with another message
+        idx = jnp.where(msgs.valid, msgs.target, v)
+        counts = jax.ops.segment_sum(jnp.ones((n,), jnp.int32), idx,
+                                     num_segments=v + 1)[:v]
+        conflicts = jnp.sum(jnp.where(msgs.valid & (counts[tgt] > 1), 1,
+                                      0))
+        return (success, conflicts.astype(jnp.int32),
+                applied.astype(jnp.int32))

@@ -125,26 +125,28 @@ def route_wave(ecfg: EngineConfig, state_l, target, payload, pending,
     if width > 1:   # block/width are static: a trace-time guard is free
         require_key_space(ecfg.block * width,
                           where="route_wave(block * wave_width)")
-    owner = target // ecfg.block
-    plan, _ = plan_buckets_sorted(owner, pending, P, Cp)
-    kept = plan.kept
-    # sentinel -1 marks empty slots through the exchange
-    buf_t = scatter_to_buckets(plan, jnp.where(kept, target, -1), P, Cp,
-                               fill=-1)
-    buf_p = scatter_to_buckets(plan, payload, P, Cp, fill=0)
-    rt = jax.lax.all_to_all(buf_t, ecfg.axis, 0, 0, tiled=True)
-    rp = _tree_all_to_all(buf_p, ecfg.axis)
+    if width > 1 and major is None:
+        raise ValueError("batch axis with wave_width > 1 needs "
+                         "per-message `major` item ids")
+    with jax.named_scope(C.PLAN_SCOPE):
+        owner = target // ecfg.block
+        plan, _ = plan_buckets_sorted(owner, pending, P, Cp)
+        kept = plan.kept
+        # sentinel -1 marks empty slots through the exchange
+        buf_t = scatter_to_buckets(plan, jnp.where(kept, target, -1), P,
+                                   Cp, fill=-1)
+        buf_p = scatter_to_buckets(plan, payload, P, Cp, fill=0)
+        if width > 1:
+            buf_l = scatter_to_buckets(plan, major, P, Cp, fill=0)
+    with jax.named_scope(C.EXCHANGE_SCOPE):
+        rt = jax.lax.all_to_all(buf_t, ecfg.axis, 0, 0, tiled=True)
+        rp = _tree_all_to_all(buf_p, ecfg.axis)
+        if width > 1:
+            rl = jax.lax.all_to_all(buf_l, ecfg.axis, 0, 0, tiled=True)
     # local commit at the owner, one per (state, payload) field pair
     shard = jax.lax.axis_index(ecfg.axis)
     rt_flat = rt.reshape(-1)
-    rl_flat = None
-    if width > 1:
-        if major is None:
-            raise ValueError("batch axis with wave_width > 1 needs "
-                             "per-message `major` item ids")
-        buf_l = scatter_to_buckets(plan, major, P, Cp, fill=0)
-        rl = jax.lax.all_to_all(buf_l, ecfg.axis, 0, 0, tiled=True)
-        rl_flat = rl.reshape(-1)
+    rl_flat = rl.reshape(-1) if width > 1 else None
     valid = (rt_flat >= 0)
     st_leaves, tdef = jax.tree_util.tree_flatten(state_l)
     pl_leaves = tdef.flatten_up_to(rp)
@@ -183,9 +185,10 @@ def route_wave(ecfg: EngineConfig, state_l, target, payload, pending,
             conflicts = res.conflicts
         succs.append(res.success)
     # FR return path: ONE reverse exchange carries every field's flags
-    back = jax.lax.all_to_all(
-        jnp.stack(succs, axis=-1).reshape(P, Cp, len(succs)),
-        ecfg.axis, 0, 0, tiled=True)
+    with jax.named_scope(C.EXCHANGE_SCOPE):
+        back = jax.lax.all_to_all(
+            jnp.stack(succs, axis=-1).reshape(P, Cp, len(succs)),
+            ecfg.axis, 0, 0, tiled=True)
     succ = tdef.unflatten(
         [gather_from_buckets(back[..., i], plan, Cp, fill=False)
          for i in range(len(succs))])
@@ -769,7 +772,8 @@ def run_distributed(alg: AlgorithmSpec, mesh, g, *,
         capacity = auto_capacity(g, P, shard_edges)
     kw = dict(axis=axis, capacity=capacity, m=m, spec=spec, batch=batch,
               max_subrounds=max_subrounds)
-    r = _Runner(alg, mesh, g, edges=edges, **kw)
+    with OT.span("runner_build", cat="engine"):
+        r = _Runner(alg, mesh, g, edges=edges, **kw)
     state, scalars, carry = r.state0, r.scalars0, r.zero_carry()
     degraded, faults, chunk_i = False, 0, 0
     chunk = (snapshot_rounds if snapshot_rounds
@@ -784,8 +788,9 @@ def run_distributed(alg: AlgorithmSpec, mesh, g, *,
         try:
             if fault_injector is not None:
                 fault_injector(chunk_i, int(carry[4]))
-            state, scalars, carry = r.run(state, scalars, carry, limit)
-            jax.block_until_ready(carry)     # surface device faults HERE
+            with OT.span("chunk", cat="engine"):
+                state, scalars, carry = r.run(state, scalars, carry, limit)
+                jax.block_until_ready(carry)  # surface device faults HERE
             snap = (state, scalars, carry)
         except KeyboardInterrupt:
             raise
@@ -805,7 +810,8 @@ def run_distributed(alg: AlgorithmSpec, mesh, g, *,
             if r.P > 1:
                 new_mesh = _shrink_mesh(r.mesh, axis, r.P - 1)
                 old_layout = r.layout
-                r = _Runner(alg, new_mesh, g, **kw)
+                with OT.span("runner_build", cat="engine"):
+                    r = _Runner(alg, new_mesh, g, **kw)
                 remapped = _remap_state(alg, g, old_layout, r.layout,
                                         state)
                 if remapped is None:

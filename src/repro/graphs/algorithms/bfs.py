@@ -53,8 +53,9 @@ def bfs(g: Graph, source, *, commit: str = "coarse", m: int | None = None,
 
     def body(state):
         dist, frontier, it, lvl, nmsg, ncf, nap = state
-        active = frontier[g.src]
-        msgs = make_messages(g.dst, dist[g.src] + 1, active)
+        with jax.named_scope(C.MESSAGES_SCOPE):
+            active = frontier[g.src]
+            msgs = make_messages(g.dst, dist[g.src] + 1, active)
         res, lvl = step(dist, msgs, lvl)
         changed = res.state != dist
         return (res.state, changed, it + 1, lvl,
@@ -102,8 +103,10 @@ def multi_source_bfs(g: Graph, sources, *, commit: str = "coarse",
 
     def body(state):
         dist, frontier, it, lvl, nmsg, ncf, nap = state
-        active = frontier.reshape(-1)[src_l]   # per-lane early-exit mask
-        msgs = lane_messages(dst_l, dist.reshape(-1)[src_l] + 1, active, v)
+        with jax.named_scope(C.MESSAGES_SCOPE):
+            active = frontier.reshape(-1)[src_l]   # per-lane early exit
+            msgs = lane_messages(dst_l, dist.reshape(-1)[src_l] + 1,
+                                 active, v)
         res, lvl = step(dist.reshape(-1), msgs, lvl)
         dist2 = res.state.reshape(lanes, v)
         return (dist2, dist2 != dist, it + 1, lvl,

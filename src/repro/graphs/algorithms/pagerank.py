@@ -33,8 +33,10 @@ def pagerank(g: Graph, *, d: float = 0.85, iters: int = 20,
 
     def body(carry, _):
         rank, conflicts, lvl = carry
-        contrib = d * rank[g.src] / deg[g.src]
-        msgs = make_messages(g.dst, contrib, jnp.ones_like(g.src, bool))
+        with jax.named_scope(C.MESSAGES_SCOPE):
+            contrib = d * rank[g.src] / deg[g.src]
+            msgs = make_messages(g.dst, contrib,
+                                 jnp.ones_like(g.src, bool))
         res, lvl = step(acc0, msgs, lvl)
         dangle = d * jnp.sum(jnp.where(dangling, rank, 0.0)) / v
         rank = (1.0 - d) / v + res.state + dangle
@@ -66,8 +68,10 @@ def personalized_pagerank(g: Graph, source, *, d: float = 0.85,
 
     def body(carry, _):
         rank, conflicts, lvl = carry
-        contrib = d * rank[g.src] / deg[g.src]
-        msgs = make_messages(g.dst, contrib, jnp.ones_like(g.src, bool))
+        with jax.named_scope(C.MESSAGES_SCOPE):
+            contrib = d * rank[g.src] / deg[g.src]
+            msgs = make_messages(g.dst, contrib,
+                                 jnp.ones_like(g.src, bool))
         res, lvl = step(acc0, msgs, lvl)
         dangle = d * jnp.sum(jnp.where(dangling, rank, 0.0))
         rank = restart * ((1.0 - d) + dangle) + res.state

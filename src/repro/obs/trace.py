@@ -7,10 +7,20 @@ events (begin/end read the tracer's clock), instants are "i" events
 binds its tracer to THE SAME clock, so fake-clock tests see
 deterministic span timestamps.
 
-Everything is inert unless the tracer is *active*: ``enabled=None``
+Every span also opens a ``jax.profiler.TraceAnnotation`` named
+``aam.<name>`` (:func:`annotate`), active tracer or not: while a
+``jax.profiler`` trace is being recorded the span sits on its thread's
+host line, on the clock of the device ops it dispatched, so an idle gap
+of the chip can be named by what the program was doing.  With no
+profile running the annotation is the profiler's own no-op, and it
+reads none of the tracer's clock.  :func:`span` is the process-global
+tracer's span, for code that has no tracer of its own (the tuner, the
+ingest, ``run_distributed``).
+
+Recording is inert unless the tracer is *active*: ``enabled=None``
 (the default) follows the ``REPRO_TRACE`` environment variable, so the
 zero-impact-when-off guarantee extends to the host side — an inactive
-span context manager performs no clock reads and allocates nothing.
+span performs no clock reads and records nothing.
 
 ``to_chrome()`` exports ``{"traceEvents": [...]}`` (Chrome tracing /
 Perfetto JSON, microsecond timestamps); :func:`validate_trace` is the
@@ -20,11 +30,19 @@ exported document.
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
+import re
 import threading
 import time
+from pathlib import Path
+
+import jax
+from jax.profiler import TraceAnnotation
 
 TRACE_SCHEMA = "aam-trace/v1"
+# prefix of the program's spans on a jax.profiler trace's host lines
+ANNOTATION_PREFIX = "aam."
 
 # tid convention for the one-process serving stack: host-side serving
 # spans vs device-side wavetap events render as two named rows
@@ -35,6 +53,43 @@ TID_DEVICE = 1
 def trace_enabled() -> bool:
     """The global toggle: ``REPRO_TRACE`` set to anything but ``0``."""
     return os.environ.get("REPRO_TRACE", "").strip() not in ("", "0")
+
+
+def key_compile_cache_on_metadata() -> None:
+    """Make JAX's persistent compile cache key on op metadata.
+
+    A profile names each device op by the ``op_name`` its executable
+    carries: the phase scopes of :mod:`repro.core.commit`.  By default
+    the cache leaves metadata out of its key, so a cache shared with
+    another version of this code serves that version's executable, and
+    the profile shows that version's scopes.  The checkout's own
+    directory is cut from source file names, so a checkout that moves
+    still finds its entries."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    if jax.config.jax_hlo_source_file_canonicalization_regex is None:
+        root = Path(__file__).resolve().parents[3]
+        jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                          "^" + re.escape(str(root) + os.sep))
+
+
+def annotate(name: str) -> TraceAnnotation:
+    """``with annotate("drain"): ...`` — the profiler span
+    ``aam.<name>`` alone, for a body whose :class:`Tracer` event is
+    recorded afterwards from timestamps already read
+    (:meth:`Tracer.complete`)."""
+    return TraceAnnotation(ANNOTATION_PREFIX + name)
+
+
+def annotated(name: str):
+    """Decorator: every call of the function runs inside
+    :func:`annotate` ``(name)``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with annotate(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
 
 
 class Tracer:
@@ -54,6 +109,9 @@ class Tracer:
         self._lock = threading.Lock()
         # per-thread stacks of open spans (orphan detection)
         self._open: dict[int, list[dict]] = {}
+        # per-thread stacks of open profiler annotations (kept whether
+        # or not the tracer is active)
+        self._annotations = threading.local()
 
     @property
     def active(self) -> bool:
@@ -63,7 +121,11 @@ class Tracer:
 
     def begin(self, name: str, *, cat: str = "serve", tid: int = TID_SERVE,
               args: dict | None = None) -> None:
-        """Open a span (reads the clock once).  Prefer :meth:`span`."""
+        """Open a span (reads the clock once when active) and its
+        profiler annotation.  Prefer :meth:`span`."""
+        ann = annotate(name)
+        ann.__enter__()
+        self._annotation_stack().append(ann)
         if not self.active:
             return
         ev = {"name": name, "cat": cat, "tid": tid, "ts": self.clock(),
@@ -73,7 +135,11 @@ class Tracer:
 
     def end(self, args: dict | None = None) -> None:
         """Close the innermost open span of this thread (one clock
-        read); no-op if none is open (e.g. tracing flipped mid-span)."""
+        read when active); no-op if none is open (e.g. tracing flipped
+        mid-span)."""
+        anns = self._annotation_stack()
+        if anns:
+            anns.pop().__exit__(None, None, None)
         if not self.active:
             return
         now = self.clock()
@@ -94,9 +160,6 @@ class Tracer:
         """``with tracer.span("drain"): ...`` — the try/finally
         guarantees a fault inside the span still closes it, so a crash →
         restore run never leaves orphans."""
-        if not self.active:
-            yield
-            return
         self.begin(name, cat=cat, tid=tid, args=args)
         try:
             yield
@@ -128,6 +191,12 @@ class Tracer:
               "args": dict(args or {})}
         with self._lock:
             self.events.append(ev)
+
+    def _annotation_stack(self) -> list:
+        stack = getattr(self._annotations, "stack", None)
+        if stack is None:
+            stack = self._annotations.stack = []
+        return stack
 
     # -- inspection / export ----------------------------------------------
 
@@ -212,3 +281,10 @@ def set_tracer(tracer: Tracer | None) -> None:
     global _TRACER
     with _TRACER_LOCK:
         _TRACER = tracer
+
+
+def span(name: str, **kw):
+    """``with span("tune"): ...`` — a span of the process-global tracer
+    (:meth:`Tracer.span`): always the profiler annotation
+    ``aam.<name>``, and a trace event when tracing is on."""
+    return get_tracer().span(name, **kw)

@@ -14,9 +14,10 @@ mid-loop: per-round conflicts, commit density, the ladder level M.
   not serialize on the host; the round index rides in the payload).
 
 Records accumulate in a process-global :class:`Collector`;
-:func:`flush_to` converts them into Chrome trace events on the device
-tid (span duration = gap to the previous record in the same stream —
-the host-side arrival cadence, which is what a round boundary costs),
+:func:`flush_to` converts them into Chrome trace instants on the device
+tid, each at the host time its callback arrived (the callback says
+when a round's numbers reached the host, not how long the round ran on
+the device: the device's own timeline is the ``jax.profiler`` trace),
 and :func:`summary` reduces them to the per-row bench fields
 (rounds, mean commit density, ladder moves).
 
@@ -154,23 +155,16 @@ def summary(recs: list[dict] | None = None) -> dict:
 
 
 def flush_to(tracer, tid: int = _trace.TID_DEVICE) -> int:
-    """Drain the collector into ``tracer`` as device-tid trace events;
-    returns the number of records flushed.  Round/commit spans get
-    ``dur`` = host gap since the previous record of their stream (first
-    record of a stream renders as a zero-width span)."""
+    """Drain the collector into ``tracer`` as device-tid instants, one
+    per record at its host arrival time ``t``, carrying the record's
+    fields as args; returns the number of records flushed."""
     recs = _COLLECTOR.drain()
     if not tracer.active:
         return len(recs)
-    prev: dict[tuple, float] = {}
     for r in recs:
-        key = (r["kind"], r["label"])
-        t = r["t"]
-        t0 = prev.get(key, t)
-        prev[key] = t
         args = {k: v for k, v in r.items()
                 if k not in ("kind", "label", "t")}
         name = (f"round[{r['label']}]" if r["kind"] == "round"
                 else f"commit[{r['label']}]")
-        tracer.complete(name, t0, t - t0, cat=r["kind"], tid=tid,
-                        args=args)
+        tracer.instant(name, cat=r["kind"], tid=tid, ts=r["t"], args=args)
     return len(recs)

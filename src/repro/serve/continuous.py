@@ -38,6 +38,7 @@ import time
 from typing import Any
 
 from repro.core import autotune as AT
+from repro.obs import trace as OT
 from repro.obs import wavetap as OW
 from repro.serve.graph_service import GraphService
 from repro.serve.product_wave import ProductWave
@@ -265,6 +266,7 @@ class ContinuousServer:
                 self.done_at[t] = svc.clock()
                 self._voided.add(t)
 
+    @OT.annotated("drain")
     def _drain_once(self) -> None:
         """One admission cycle: product kinds board continuous product
         waves (with mid-wave insertion); everything else takes the

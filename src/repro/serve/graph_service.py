@@ -408,6 +408,7 @@ class GraphService:
 
     # -- execution --------------------------------------------------------
 
+    @OT.annotated("drain")
     def drain(self) -> dict:
         """Execute every queued query in fused batch-axis waves.
 

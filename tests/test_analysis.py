@@ -169,6 +169,34 @@ def test_race_detector_sees_through_while_loop():
     assert not rep.ok
 
 
+def test_race_scope_is_a_whole_path_component():
+    """A raw write under ``aam_commit/aam_commit_stats`` is still inside
+    the commit; a scope whose name only starts with ``aam_commit`` is
+    not.  The linter reads the scope name from the program's constant."""
+    import jax
+    from repro.core.commit import COMMIT_SCOPE, STATS_SCOPE
+
+    def scatter(d):
+        return d.at[jnp.arange(8) % 4].min(d[jnp.arange(8)] + 1)
+
+    def nested(state):
+        with jax.named_scope(COMMIT_SCOPE), jax.named_scope(STATS_SCOPE):
+            return {"dist": scatter(state["dist"])}
+
+    def prefix_only(state):
+        with jax.named_scope(STATS_SCOPE):
+            return {"dist": scatter(state["dist"])}
+
+    st = {"dist": jnp.zeros((8,), jnp.int32)}
+    assert waverace._SCOPE == COMMIT_SCOPE
+    rep = waverace.check_traceable("nested", nested, st)
+    assert rep.ok and rep.commits == 1
+    rep = waverace.check_traceable("prefix", prefix_only, st)
+    assert not rep.ok and rep.findings[0].primitive == "scatter-min"
+    assert waverace.scope_components("vmap(aam_commit)/aam_commit_stats") \
+        == ["vmap", "aam_commit", "aam_commit_stats"]
+
+
 # -- CLI smoke (the tier-1 acceptance gate) ---------------------------------
 
 @pytest.fixture(scope="module")

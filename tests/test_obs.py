@@ -10,6 +10,7 @@ import dataclasses
 import json
 import math
 import os
+import re
 import shutil
 import tempfile
 
@@ -176,6 +177,200 @@ def test_tracer_enabled_none_follows_env(monkeypatch):
     assert not tr.active
 
 
+def _profile(tmp_path, body):
+    """Run ``body`` under a recorded CPU ``jax.profiler`` trace; return
+    its ``/host:CPU`` plane as ``{line name: [(name, start, end, stats)]}``
+    (one clock, nanoseconds)."""
+    import glob
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    plane, = [p for p in pd.planes if p.name == "/host:CPU"]
+    return {ln.name: [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                       dict(e.stats)) for e in ln.events]
+            for ln in plane.lines}
+
+
+def test_spans_land_on_the_profiler_host_line_around_their_ops(tmp_path):
+    """Every span is also the profiler annotation ``aam.<name>``, active
+    tracer or not: it sits on the host line of the thread that
+    dispatched the jitted call, on the clock of the call's ops, and
+    encloses them; the inactive tracer reads none of its clock."""
+    @jax.jit
+    def f(x):
+        return jnp.sort(x * 3)
+
+    x = jnp.arange(1 << 14, dtype=jnp.int32)[::-1]
+    f(x).block_until_ready()
+    clk = CountingClock()
+    tr = OT.Tracer(clock=clk, enabled=False)
+
+    def body():
+        with OT.span("outer"):
+            with tr.span("probe"):
+                for _ in range(3):
+                    f(x).block_until_ready()
+            tr.begin("paired")
+            f(x).block_until_ready()
+            tr.end()
+
+    lines = _profile(tmp_path, body)
+    assert clk.reads == 0 and tr.events == []
+    where = {ev[0]: ln for ln, evs in lines.items() for ev in evs
+             if ev[0].startswith(OT.ANNOTATION_PREFIX)}
+    assert set(where) == {"aam.outer", "aam.probe", "aam.paired"}
+    host = lines[where["aam.probe"]]
+    assert len(set(where.values())) == 1
+    assert any(ev[0] == "PjitFunction(f)" for ev in host)
+    span = {ev[0]: ev for ev in host}
+    ops = sorted(ev for evs in lines.values() for ev in evs
+                 if "hlo_op" in ev[3])
+    assert len(ops) >= 4
+    _, s0, e0, _ = span["aam.outer"]
+    _, s1, e1, _ = span["aam.probe"]
+    assert s0 <= s1 < e1 <= e0
+    assert all(s0 <= s and e <= e0 for _, s, e, _ in ops)
+    assert sum(s1 <= s and e <= e1 for _, s, e, _ in ops) >= 3
+
+
+def _root_op_names(hlo_text: str) -> list:
+    """``(opcode, op_name)`` of each instruction of the entry and loop
+    computations (fused computations' insides left out: a fusion runs
+    as one device op, named by its root)."""
+    import re
+    out, fused = [], False
+    for line in hlo_text.splitlines():
+        if line and not line.startswith(" "):
+            fused = line.startswith(("%fused", "fused"))
+            continue
+        m = re.match(r"\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*\S+\s+([\w\-]+)\(",
+                     line)
+        if not m or fused:
+            continue
+        op = re.search(r'op_name="([^"]*)"', line)
+        out.append((m.group(1), op.group(1) if op else ""))
+    return out
+
+
+PHASE_SCOPES = ("aam_messages", "aam_commit", "aam_commit_stats")
+
+
+def _phase(op_name: str) -> str:
+    import re
+    parts = [c for c in re.split(r"[/()]", op_name) if c]
+    for scope in ("aam_commit_stats", "aam_commit", "aam_messages"):
+        if scope in parts:
+            return scope
+    return "other"
+
+
+@pytest.mark.parametrize("alg", ["bfs", "pagerank"])
+def test_round_body_ops_are_named_by_phase(alg):
+    """The compiled round loop names its phases: message building under
+    ``aam_messages``, the commit under ``aam_commit``, its bookkeeping
+    under ``aam_commit_stats`` nested in it; every other op is
+    ``other``.  The names live in the module constants."""
+    from repro.core import commit as C
+    from repro.graphs.algorithms.bfs import bfs
+    from repro.graphs.algorithms.pagerank import pagerank
+    assert (C.MESSAGES_SCOPE, C.COMMIT_SCOPE, C.STATS_SCOPE) == (
+        "aam_messages", "aam_commit", "aam_commit_stats")
+    g = kronecker(8, 8, seed=1)
+    spec = CommitSpec(backend="atomic")           # stats on, as `auto`
+    if alg == "bfs":
+        text = bfs.lower(g, jnp.int32(0), spec=spec).compile().as_text()
+    else:
+        text = pagerank.lower(g, iters=3, spec=spec).compile().as_text()
+    body = [(op, n) for op, n in _root_op_names(text) if "/body/" in n]
+    phases = {}
+    for op, name in body:
+        phases.setdefault(_phase(name), []).append(op)
+        if "aam_commit_stats" in name:        # nested, never alone
+            parts = name.split("/")
+            assert parts.index("aam_commit") < \
+                parts.index("aam_commit_stats"), name
+    assert set(phases) <= {"aam_messages", "aam_commit",
+                           "aam_commit_stats", "other"}
+    assert {"aam_messages", "aam_commit", "aam_commit_stats"} <= \
+        set(phases), phases
+    # the min commit's scatter is the commit; its success bookkeeping
+    # (a segment-min over the messages) is the nested stats scope
+    if alg == "bfs":
+        assert "scatter" in phases["aam_commit"]
+        assert "scatter" in phases["aam_commit_stats"]
+
+
+def test_compile_cache_keeps_each_programs_own_scopes(tmp_path):
+    """Two programs that differ only in a scope get two entries of a
+    shared persistent compile cache: an executable loaded from it
+    carries this code's op_names, never another version's (by default
+    JAX leaves metadata out of the key).  Importing the program keys
+    the cache on metadata, with the checkout's directory cut."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    regex = jax.config.jax_hlo_source_file_canonicalization_regex
+    assert regex and re.sub(regex, "", os.path.join(
+        root, "src", "repro", "core", "commit.py")) == \
+        os.path.join("src", "repro", "core", "commit.py")
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    old = {k: getattr(jax.config, k) for k in keys}
+    jax.config.update(keys[0], str(tmp_path))
+    jax.config.update(keys[1], 0)
+    jax.config.update(keys[2], 0)
+    cc.reset_cache()
+
+    def hlo(scope):
+        def f(x):
+            with jax.named_scope(scope):
+                return jnp.sin(x) * 2
+        return jax.jit(f).lower(jnp.ones(8)).compile().as_text()
+
+    try:
+        assert "scope_a" in hlo("scope_a")
+        assert "scope_b" in hlo("scope_b")
+        assert len(os.listdir(tmp_path)) >= 2
+    finally:
+        for k, v in old.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
+def test_tuner_tune_s_counts_races_not_cache_hits(monkeypatch):
+    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", "off")
+    rec = OT.Tracer(enabled=True)
+    old = OT.get_tracer()
+    OT.set_tracer(rec)
+    try:
+        tuner = AT.AutoTuner(ns=(8, 16), repeats=1)
+        kw = dict(sort=True, stats=False, tile_m=256, block_v=512,
+                  interpret=None, v=256, op="min")
+        assert tuner.tune_s == 0.0
+        tuner.race({"atomic": None, "coarse": None}, 64, **kw)
+        after_race = tuner.tune_s
+        runs = tuner.timed_runs
+        assert after_race > 0 and runs == 2
+        tuner.race({"atomic": None, "coarse": None}, 64, **kw)  # cached
+        assert tuner.tune_s == after_race and tuner.timed_runs == runs
+        tuner.calibrate(with_pallas=False, sort=True, stats=False,
+                        tile_m=256, block_v=512, interpret=None)
+        assert tuner.tune_s > after_race
+    finally:
+        OT.set_tracer(old)
+    tunes = [e for e in rec.events if e["name"] == "tune"]
+    assert [e["args"]["what"] for e in tunes] == ["race", "calibrate"]
+    assert sum(e["dur"] for e in tunes) == pytest.approx(tuner.tune_s,
+                                                         rel=0.05)
+
+
 # -- metrics ----------------------------------------------------------------
 
 
@@ -282,11 +477,21 @@ def test_wavetap_flush_renders_device_events():
     OW.collector().add({"kind": "round", "label": "x", "t": 1.5,
                         "round": 1, "conflicts": 0, "messages": 4,
                         "subrounds": 1, "level": 1, "shard": 0})
-    tr = OT.Tracer(clock=FakeClock(), enabled=True)
+    clk = CountingClock()
+    tr = OT.Tracer(clock=clk, enabled=True)
     assert OW.flush_to(tr) == 2
     assert OW.records() == []           # drained
     assert [e["tid"] for e in tr.events] == [OT.TID_DEVICE] * 2
-    assert tr.events[1]["dur"] == pytest.approx(0.5)
+    # each record is an instant at its host arrival time, with its
+    # fields: the gap between callbacks is no device duration
+    assert [e["ph"] for e in tr.events] == ["i", "i"]
+    assert [e["ts"] for e in tr.events] == [1.0, 1.5]
+    assert all("dur" not in e for e in tr.events)
+    assert tr.events[1]["name"] == "round[x]"
+    assert tr.events[1]["args"] == {"round": 1, "conflicts": 0,
+                                    "messages": 4, "subrounds": 1,
+                                    "level": 1, "shard": 0}
+    assert clk.reads == 0               # arrival times, not the clock
     assert OT.validate_trace(tr.to_chrome()) == []
 
 
