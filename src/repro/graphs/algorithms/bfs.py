@@ -6,6 +6,14 @@ commit with the MF ``min`` operator (losers fail silently — no rollback
 needed on TPU, DESIGN.md §2); the next frontier is the set of vertices whose
 distance changed.  ``commit="atomic"`` is the fine-grained Graph500-style
 baseline; ``commit="coarse"`` is AAM with transaction size ``m``.
+
+A round builds its messages from ONE edge-sized gather: the frontier
+folds into the vertex-sized ``fd = where(frontier, dist, INF)``, and
+``fd[src]`` gives both the activity (``fd < INF``: every frontier vertex
+has a finite distance) and the payload ``fd + 1``.  That is exact, not
+approximate: on active lanes the payload is ``dist[src] + 1``, and every
+commit tier drops inactive lanes by ``valid`` without reading their
+payload.
 """
 from __future__ import annotations
 
@@ -53,9 +61,12 @@ def bfs(g: Graph, source, *, commit: str = "coarse", m: int | None = None,
 
     def body(state):
         dist, frontier, it, lvl, nmsg, ncf, nap = state
+        # one E-gather of the frontier folded into dist; lanes off the
+        # frontier read INF and are dropped by ``valid`` (module doc)
         with jax.named_scope(C.MESSAGES_SCOPE):
-            active = frontier[g.src]
-            msgs = make_messages(g.dst, dist[g.src] + 1, active)
+            fd = jnp.where(frontier, dist, INF)[g.src]
+            active = fd < INF
+            msgs = make_messages(g.dst, fd + 1, active)
         res, lvl = step(dist, msgs, lvl)
         changed = res.state != dist
         return (res.state, changed, it + 1, lvl,
@@ -103,10 +114,12 @@ def multi_source_bfs(g: Graph, sources, *, commit: str = "coarse",
 
     def body(state):
         dist, frontier, it, lvl, nmsg, ncf, nap = state
+        # one [L·E] gather of the frontier folded into dist (module doc);
+        # converged lanes read INF and emit nothing: per-lane early exit
         with jax.named_scope(C.MESSAGES_SCOPE):
-            active = frontier.reshape(-1)[src_l]   # per-lane early exit
-            msgs = lane_messages(dst_l, dist.reshape(-1)[src_l] + 1,
-                                 active, v)
+            fd = jnp.where(frontier, dist, INF).reshape(-1)[src_l]
+            active = fd < INF
+            msgs = lane_messages(dst_l, fd + 1, active, v)
         res, lvl = step(dist.reshape(-1), msgs, lvl)
         dist2 = res.state.reshape(lanes, v)
         return (dist2, dist2 != dist, it + 1, lvl,

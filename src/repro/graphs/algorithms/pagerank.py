@@ -1,10 +1,14 @@
 """PageRank — FF&AS atomic active messages (paper §3.3.1, Listing 3).
 
 Every edge carries ``d * rank[src] / out_deg[src]`` to its destination; the
-commit is an Always-Succeed accumulate.  On TPU the AS commit is a conflict-
-free segment-sum — the paper's HTM abort storm for ACC (§5.4.2) disappears
-by construction (DESIGN.md §2).  ``pagerank_baseline`` is the PBGL-like
-per-edge scatter path used as the Fig-7 comparison.
+commit is an Always-Succeed accumulate.  A round forms ``d * rank / deg``
+once per vertex and gathers it over ``src`` ONCE: the same float32
+multiply and divide on the same operands as per-edge
+``d * rank[src] / deg[src]``, so the messages are bit-identical to that
+formula's, for one edge-sized gather a round.  On TPU the AS commit is a
+conflict-free segment-sum — the paper's HTM abort storm for ACC (§5.4.2)
+disappears by construction (DESIGN.md §2).  ``pagerank_baseline`` is the
+PBGL-like per-edge scatter path used as the Fig-7 comparison.
 """
 from __future__ import annotations
 
@@ -34,7 +38,7 @@ def pagerank(g: Graph, *, d: float = 0.85, iters: int = 20,
     def body(carry, _):
         rank, conflicts, lvl = carry
         with jax.named_scope(C.MESSAGES_SCOPE):
-            contrib = d * rank[g.src] / deg[g.src]
+            contrib = (d * rank / deg)[g.src]   # one E-gather (module doc)
             msgs = make_messages(g.dst, contrib,
                                  jnp.ones_like(g.src, bool))
         res, lvl = step(acc0, msgs, lvl)
@@ -69,7 +73,7 @@ def personalized_pagerank(g: Graph, source, *, d: float = 0.85,
     def body(carry, _):
         rank, conflicts, lvl = carry
         with jax.named_scope(C.MESSAGES_SCOPE):
-            contrib = d * rank[g.src] / deg[g.src]
+            contrib = (d * rank / deg)[g.src]   # one E-gather (module doc)
             msgs = make_messages(g.dst, contrib,
                                  jnp.ones_like(g.src, bool))
         res, lvl = step(acc0, msgs, lvl)
@@ -112,7 +116,7 @@ def multi_source_pagerank(g: Graph, sources, *, d: float = 0.85,
 
     def body(carry, _):
         rank, conflicts, lvl = carry
-        contrib = d * rank[:, g.src] / deg[g.src][None, :]
+        contrib = (d * rank / deg[None, :])[:, g.src]   # one gather, exact
         msgs = lane_messages(dst_l, contrib, valid_l, v)
         res, lvl = step(acc0, msgs, lvl)
         dangle = d * jnp.sum(jnp.where(dangling[None, :], rank, 0.0),
@@ -143,7 +147,7 @@ def _union_ppr(g: Graph, sources_flat, gov, d, *, iters: int,
 
     def body(carry, _):
         rank, lvl = carry
-        contrib = d * rank[g.src] / deg[g.src]
+        contrib = (d * rank / deg)[g.src]       # one E-gather, exact
         msgs = make_messages(g.dst, contrib, jnp.ones_like(g.src, bool))
         res, lvl = step(acc0, msgs, lvl)
         dm = jax.ops.segment_sum(jnp.where(dangling, rank, 0.0), gov,

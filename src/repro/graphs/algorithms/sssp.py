@@ -1,5 +1,7 @@
 """SSSP (Bellman-Ford label-correcting) — FF&MF messages, weighted ``min``
-commit.  Same AAM structure as BFS with ``dist[src] + w`` payloads."""
+commit.  Same AAM structure as BFS with ``dist[src] + w`` payloads, built
+from the same one-gather fold: ``fd = where(frontier, dist, INF)[src]``,
+active where ``fd < INF``, payload ``fd + w`` (see :mod:`.bfs`)."""
 from __future__ import annotations
 
 from functools import partial
@@ -32,8 +34,10 @@ def sssp(g: Graph, source, *, commit: str = "coarse", m: int | None = None,
 
     def body(state):
         dist, frontier, it, lvl = state
-        active = frontier[g.src]
-        msgs = make_messages(g.dst, dist[g.src] + g.weights, active)
+        # one E-gather; lanes off the frontier read INF, dropped by valid
+        fd = jnp.where(frontier, dist, INF)[g.src]
+        active = fd < INF
+        msgs = make_messages(g.dst, fd + g.weights, active)
         res, lvl = step(dist, msgs, lvl)
         return res.state, res.state != dist, it + 1, lvl
 
@@ -72,9 +76,10 @@ def multi_source_sssp(g: Graph, sources, *, commit: str = "coarse",
 
     def body(state):
         dist, frontier, it, lvl = state
-        active = frontier[:, g.src]
-        msgs = lane_messages(dst_l, dist[:, g.src] + g.weights[None, :],
-                             active, v)
+        # one [L, E] gather; lanes off the frontier read INF, dropped
+        fd = jnp.where(frontier, dist, INF)[:, g.src]
+        active = fd < INF
+        msgs = lane_messages(dst_l, fd + g.weights[None, :], active, v)
         res, lvl = step(dist.reshape(-1), msgs, lvl)
         dist2 = res.state.reshape(lanes, v)
         return dist2, dist2 != dist, it + 1, lvl

@@ -1,19 +1,29 @@
 """Graph algorithms vs networkx / reference oracles (property-based over
 generated graph families)."""
+from functools import partial
+
+import jax
 import jax.numpy as jnp
 import networkx as nx
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, strategies as st
 
+from repro.core import autotune as AT
+from repro.core.commit import CommitSpec
+from repro.core.messages import lane_messages, make_messages
 from repro.graphs.csr import from_edges
 from repro.graphs.generators import (erdos_renyi, grid2d, kronecker,
                                      preferential, random_weights)
-from repro.graphs.algorithms.bfs import bfs, bfs_reference
+from repro.graphs.algorithms import bfs as bfs_mod, sssp as sssp_mod
+from repro.graphs.algorithms.bfs import bfs, bfs_reference, multi_source_bfs
 from repro.graphs.algorithms.boruvka import boruvka, mst_reference
 from repro.graphs.algorithms.coloring import coloring, validate_coloring
-from repro.graphs.algorithms.pagerank import pagerank, pagerank_reference
-from repro.graphs.algorithms.sssp import sssp, sssp_reference
+from repro.graphs.algorithms.pagerank import (multi_source_pagerank,
+                                              pagerank, pagerank_reference,
+                                              personalized_pagerank)
+from repro.graphs.algorithms.sssp import (multi_source_sssp, sssp,
+                                          sssp_reference)
 from repro.graphs.algorithms.stconn import st_connectivity, st_reference
 
 SET = dict(max_examples=10, deadline=None)
@@ -137,3 +147,159 @@ def test_bfs_conflict_telemetry_nonzero_on_dense_graph():
     r = bfs(g, src, commit="coarse", m=128)
     assert int(r.conflicts) > 0
     assert int(r.applied) <= int(r.messages)
+
+
+# --- one edge-sized gather a round, bit for bit --------------------------
+# The round bodies as they were before the message build was folded onto
+# one gather: the activity and the payload gathered over ``src`` apart.
+
+
+@partial(jax.jit, static_argnames=("spec", "weighted"))
+def _two_gather_min(g, sources, *, spec, weighted):
+    """``bfs``/``sssp`` (``sources`` a scalar) or their lane forms (a
+    vector): ``frontier[src]`` and ``dist[src]`` gathered apart.
+    Returns (dist, rounds, messages, conflicts, applied)."""
+    inf, dtype = ((sssp_mod.INF, jnp.float32) if weighted
+                  else (bfs_mod.INF, jnp.int32))
+    lanes = sources.ndim == 1
+    v, e = g.num_vertices, g.src.shape[0]
+    srcs = jnp.atleast_1d(sources)
+    nl = srcs.shape[0]
+    lidx = jnp.arange(nl, dtype=jnp.int32)
+    dist0 = jnp.full((nl, v), inf, dtype).at[lidx, srcs].set(0)
+    frontier0 = jnp.zeros((nl, v), bool).at[lidx, srcs].set(True)
+    src_l = (lidx[:, None] * v + g.src[None, :]).reshape(-1)
+    dst_l = jnp.broadcast_to(g.dst, (nl, e))
+    w = g.weights if weighted else 1
+    step, lvl0 = AT.make_commit_step(spec, "min", dist0.reshape(-1),
+                                     n=nl * e, axis_width=nl)
+
+    def cond(state):
+        _, frontier, it, *_ = state
+        return jnp.any(frontier) & (it < v)
+
+    def body(state):
+        dist, frontier, it, lvl, nmsg, ncf, nap = state
+        active = frontier[src_l]
+        payload = dist[src_l].reshape(nl, e) + w
+        if lanes:
+            msgs = lane_messages(dst_l, payload, active.reshape(nl, e), v)
+        else:
+            msgs = make_messages(g.dst, payload[0], active)
+        res, lvl = step(dist, msgs, lvl)
+        return (res.state, res.state != dist, it + 1, lvl,
+                nmsg + jnp.sum(active.astype(jnp.int32)),
+                ncf + res.conflicts, nap + res.applied)
+
+    z = jnp.zeros((), jnp.int32)
+    dist, _, rounds, _, nmsg, ncf, nap = jax.lax.while_loop(
+        cond, body, (dist0.reshape(-1), frontier0.reshape(-1), z, lvl0,
+                     z, z, z))
+    return (dist.reshape(nl, v) if lanes else dist), rounds, nmsg, ncf, nap
+
+
+@partial(jax.jit, static_argnames=("spec", "kind", "iters"))
+def _two_gather_pagerank(g, sources, *, spec, kind, d, iters):
+    """``pagerank`` (``kind`` "global"), ``personalized_pagerank``
+    ("ppr") or ``multi_source_pagerank`` ("lanes"): ``rank[src]`` and
+    ``deg[src]`` gathered apart.  Returns (rank, conflicts)."""
+    v, e = g.num_vertices, g.src.shape[0]
+    deg = jnp.maximum(g.degrees, 1).astype(jnp.float32)
+    dangling = g.degrees == 0
+    z = jnp.zeros((), jnp.int32)
+    if kind == "lanes":
+        nl = sources.shape[0]
+        lidx = jnp.arange(nl, dtype=jnp.int32)
+        restart = jnp.zeros((nl, v), jnp.float32).at[lidx, sources].set(1.0)
+        dst_l = jnp.broadcast_to(g.dst, (nl, e))
+        acc0 = jnp.zeros((nl * v,), jnp.float32)
+        step, lvl0 = AT.make_commit_step(spec, "add", acc0, n=nl * e,
+                                         axis_width=nl)
+
+        def body(carry, _):
+            rank, conflicts, lvl = carry
+            contrib = d * rank[:, g.src] / deg[g.src][None, :]
+            msgs = lane_messages(dst_l, contrib, jnp.ones((nl, e), bool), v)
+            res, lvl = step(acc0, msgs, lvl)
+            dangle = d * jnp.sum(jnp.where(dangling[None, :], rank, 0.0),
+                                 axis=1)
+            rank = restart * ((1.0 - d) + dangle[:, None]) \
+                + res.state.reshape(nl, v)
+            return (rank, conflicts + res.conflicts, lvl), None
+
+        (rank, conflicts, _), _ = jax.lax.scan(
+            body, (restart, z, lvl0), None, length=iters)
+        return rank, conflicts
+    acc0 = jnp.zeros((v,), jnp.float32)
+    step, lvl0 = AT.make_commit_step(spec, "add", acc0, n=e)
+    restart = jnp.zeros((v,), jnp.float32).at[sources].set(1.0)
+
+    def body(carry, _):
+        rank, conflicts, lvl = carry
+        contrib = d * rank[g.src] / deg[g.src]
+        msgs = make_messages(g.dst, contrib, jnp.ones_like(g.src, bool))
+        res, lvl = step(acc0, msgs, lvl)
+        if kind == "global":
+            dangle = d * jnp.sum(jnp.where(dangling, rank, 0.0)) / v
+            rank = (1.0 - d) / v + res.state + dangle
+        else:
+            dangle = d * jnp.sum(jnp.where(dangling, rank, 0.0))
+            rank = restart * ((1.0 - d) + dangle) + res.state
+        return (rank, conflicts + res.conflicts, lvl), None
+
+    rank0 = (jnp.full((v,), 1.0 / v, jnp.float32) if kind == "global"
+             else restart)
+    (rank, conflicts, _), _ = jax.lax.scan(
+        body, (rank0, z, lvl0), None, length=iters)
+    return rank, conflicts
+
+
+FOLD_GRAPH = random_weights(kronecker(9, 8, seed=4), seed=3)
+FOLD_SPECS = {"atomic": CommitSpec(backend="atomic"),
+              "coarse": CommitSpec(backend="coarse", m=256)}
+
+
+def _fold_sources(g):
+    """The hub, a vertex of median degree, and an isolated vertex (its
+    lane converges in round one)."""
+    deg = np.asarray(g.degrees)
+    order = np.argsort(deg, kind="stable")
+    return jnp.asarray([order[-1], order[len(order) // 2], order[0]],
+                       jnp.int32)
+
+
+@pytest.mark.parametrize("backend", sorted(FOLD_SPECS))
+@pytest.mark.parametrize("entry", [
+    "bfs", "multi_source_bfs", "sssp", "multi_source_sssp", "pagerank",
+    "personalized_pagerank", "multi_source_pagerank"])
+def test_one_gather_messages_match_two_gather_body(entry, backend):
+    """Folding each source vertex's activity and payload (or PageRank's
+    ``d * rank / deg``) into one vertex-sized vector before the gather
+    leaves every output bit-identical: distances and ranks, rounds, and
+    the message, conflict and applied counters."""
+    g, spec = FOLD_GRAPH, FOLD_SPECS[backend]
+    srcs = _fold_sources(g)
+    assert int(g.degrees[srcs[2]]) == 0 and int(g.degrees[srcs[0]]) > 0
+    if entry in ("bfs", "multi_source_bfs", "sssp", "multi_source_sssp"):
+        weighted = entry.endswith("sssp")
+        sources = srcs if entry.startswith("multi") else srcs[0]
+        fn = {"bfs": bfs, "multi_source_bfs": multi_source_bfs,
+              "sssp": sssp, "multi_source_sssp": multi_source_sssp}[entry]
+        out = fn(g, sources, spec=spec)
+        got = ((out.dist, out.rounds, out.messages, out.conflicts,
+                out.applied) if not weighted else tuple(out))
+        want = _two_gather_min(g, sources, spec=spec, weighted=weighted)
+        assert int(want[1]) > 2
+    else:
+        kw = dict(d=0.85, iters=10, spec=spec)
+        if entry == "pagerank":
+            got = pagerank(g, **kw)
+            want = _two_gather_pagerank(g, srcs[0], kind="global", **kw)
+        elif entry == "personalized_pagerank":
+            got = personalized_pagerank(g, srcs[0], **kw)
+            want = _two_gather_pagerank(g, srcs[0], kind="ppr", **kw)
+        else:
+            got = multi_source_pagerank(g, srcs, **kw)
+            want = _two_gather_pagerank(g, srcs, kind="lanes", **kw)
+    for a, b in zip(got, want, strict=False):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

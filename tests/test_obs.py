@@ -239,22 +239,23 @@ def test_spans_land_on_the_profiler_host_line_around_their_ops(tmp_path):
     assert sum(s1 <= s and e <= e1 for _, s, e, _ in ops) >= 3
 
 
-def _root_op_names(hlo_text: str) -> list:
-    """``(opcode, op_name)`` of each instruction of the entry and loop
-    computations (fused computations' insides left out: a fusion runs
-    as one device op, named by its root)."""
+def _root_op_names(hlo_text: str, fused: bool = False) -> list:
+    """``(opcode, op_name, shape)`` of each instruction of the entry and
+    loop computations (fused computations' insides left out: a fusion
+    runs as one device op, named by its root).  ``fused=True`` keeps
+    the instructions inside fusions too."""
     import re
-    out, fused = [], False
+    out, inside = [], False
     for line in hlo_text.splitlines():
         if line and not line.startswith(" "):
-            fused = line.startswith(("%fused", "fused"))
+            inside = line.startswith(("%fused", "fused"))
             continue
-        m = re.match(r"\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*\S+\s+([\w\-]+)\(",
-                     line)
-        if not m or fused:
+        m = re.match(
+            r"\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(\S+)\s+([\w\-]+)\(", line)
+        if not m or (inside and not fused):
             continue
         op = re.search(r'op_name="([^"]*)"', line)
-        out.append((m.group(1), op.group(1) if op else ""))
+        out.append((m.group(2), op.group(1) if op else "", m.group(1)))
     return out
 
 
@@ -287,7 +288,7 @@ def test_round_body_ops_are_named_by_phase(alg):
         text = bfs.lower(g, jnp.int32(0), spec=spec).compile().as_text()
     else:
         text = pagerank.lower(g, iters=3, spec=spec).compile().as_text()
-    body = [(op, n) for op, n in _root_op_names(text) if "/body/" in n]
+    body = [(op, n) for op, n, _ in _root_op_names(text) if "/body/" in n]
     phases = {}
     for op, name in body:
         phases.setdefault(_phase(name), []).append(op)
@@ -304,6 +305,33 @@ def test_round_body_ops_are_named_by_phase(alg):
     if alg == "bfs":
         assert "scatter" in phases["aam_commit"]
         assert "scatter" in phases["aam_commit_stats"]
+
+
+@pytest.mark.parametrize("alg", ["bfs", "pagerank"])
+def test_round_messages_take_one_edge_sized_gather(alg):
+    """A round builds its messages from ONE gather over the edge list:
+    the frontier (BFS) or the degree (PageRank) is folded into a
+    vertex-sized vector first.  Gathers are counted inside fusions too,
+    where XLA may hide a second one in the first one's consumer; the
+    commit's own bookkeeping gather is not a message gather."""
+    from repro.graphs.algorithms.bfs import bfs
+    from repro.graphs.algorithms.pagerank import pagerank
+    g = kronecker(8, 8, seed=1)
+    e = g.src.shape[0]
+    spec = CommitSpec(backend="atomic")
+    if alg == "bfs":
+        text = bfs.lower(g, jnp.int32(0), spec=spec).compile().as_text()
+    else:
+        text = pagerank.lower(g, iters=3, spec=spec).compile().as_text()
+
+    def elements(shape):
+        dims = re.search(r"\[([\d,]*)\]", shape).group(1)
+        return math.prod(int(x) for x in dims.split(",") if x)
+
+    gathers = [n for op, n, shape in _root_op_names(text, fused=True)
+               if op == "gather" and "/body/" in n
+               and _phase(n) == "aam_messages" and elements(shape) == e]
+    assert len(gathers) == 1, gathers
 
 
 def test_compile_cache_keeps_each_programs_own_scopes(tmp_path):
