@@ -143,7 +143,7 @@ def run_trace_off_clean() -> list[str]:
                               capacity=64, m=8, spec=sp, batch=cap.batch,
                               max_subrounds=8)
                 return str(jax.make_jaxpr(r._jfn)(
-                    r.state0, r.scalars0, r.zero_carry(),
+                    *cap.alg.init(cap.g, r.layout), r.zero_carry(),
                     jnp.asarray(1, jnp.int32), *r.arrays))
 
             # one runner per algorithm — the tap placement is per-engine,

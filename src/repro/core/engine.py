@@ -34,7 +34,9 @@ bits) through one bucket plan and one exchange per field.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import os
 from typing import Any, Callable
 
 import jax
@@ -486,12 +488,28 @@ class AlgorithmSpec:
                   :class:`WaveRuntime`, return the globally-consistent
                   ``active`` bool (False terminates the loop).
     max_rounds:   ``(g, layout) -> int`` round cap.
+    reusable:     ``round_fn`` and ``max_rounds`` capture nothing per
+                  call (per-call data enters through ``init``'s state),
+                  so one runner serves every run that keys alike:
+                  :func:`run_distributed` keeps it in :data:`RUNNERS`.
+                  Otherwise the run builds a runner of its own and
+                  drops it on return.
+    balance:      every ``state`` leaf is vertex state ([vpad, ...],
+                  indexed by vertex id) and no message carries a vertex
+                  id, so on a mesh of several shards the runner may
+                  number the vertices internally so that every owner
+                  range holds an equal share of the edges
+                  (:func:`repro.graphs.csr.balanced_numbering`).  The
+                  state enters and leaves the runner in the caller's
+                  numbering.
     """
     name: str
     message_type: str
     init: Callable[..., Any]
     round_fn: Callable[..., Any]
     max_rounds: Callable[..., int]
+    reusable: bool = False
+    balance: bool = False
 
 
 @jax.tree_util.register_dataclass
@@ -538,50 +556,95 @@ def telemetry_return(base, res: "DistributedResult", telemetry: bool):
     return (base, res)
 
 
+@dataclasses.dataclass(frozen=True)
+class _PlacedEdges:
+    """A graph's 1-D edge partition, its five ``[P, emax]`` arrays placed
+    row-per-shard on the mesh (``NamedSharding(mesh, P(axis))``).  A
+    balanced partition also holds its numbering ``(to_orig, to_int)``,
+    replicated; ``order`` is empty otherwise."""
+    arrays: tuple       # (src, dst, weight, valid, eid) device arrays
+    layout: ShardLayout
+    order: tuple = ()
+
+
+def _balanced(alg: AlgorithmSpec, num_shards: int, batch) -> bool:
+    """Whether ``alg``'s runner numbers the vertices by
+    :func:`repro.graphs.csr.balanced_numbering`: a ``balance`` algorithm
+    over several shards, without a batch axis (whose flat ids the
+    coalescing keys rely on)."""
+    return alg.balance and num_shards > 1 and batch is None
+
+
+def _place_edges(g, mesh, axis: str, edges=None, *,
+                 balance: bool = False) -> _PlacedEdges:
+    from jax.sharding import NamedSharding, PartitionSpec as Ps
+    from repro.graphs.csr import partition_edges, partition_edges_balanced
+
+    P = mesh.shape[axis]
+    order = ()
+    with OT.span("partition", cat="engine"):
+        if balance:
+            *edges, order = partition_edges_balanced(g, P)
+            order = tuple(jax.device_put(a, NamedSharding(mesh, Ps()))
+                          for a in order)
+        elif edges is None:
+            edges = partition_edges(g, P)
+        (src, dst, w, val, eid), part = edges
+        rows = NamedSharding(mesh, Ps(axis))
+        arrays = tuple(jax.device_put(a, rows)
+                       for a in (src, dst, w, val, eid))
+    return _PlacedEdges(arrays, ShardLayout(P, part.block, src.shape[1],
+                                            g.num_vertices, g.num_edges),
+                        order)
+
+
 class _Runner:
     """One compiled round-loop over one mesh shape.
 
-    Owns the partition, layout, calibrated tuner policy, and the jitted
-    shard_map'd loop body for a fixed (mesh, P).  The loop carry
+    Owns the placed partition, layout, calibrated tuner policy, and the
+    jitted shard_map'd loop body for a fixed (mesh, P).  The loop carry
     ``(conflicts, subrounds, delivered_all, level, it, active)`` enters
     and leaves as replicated scalars, and the round cap is a TRACED
     ``limit`` — so the same compiled function serves both the single-shot
     path (limit = max_rounds) and the chunked/degraded path (limit = next
     snapshot boundary), and a degraded continuation re-enters mid-run.
+    Nothing in it depends on a run's initial state beyond its layout, so
+    :data:`RUNNERS` hands one runner to every run that keys alike.
     """
 
     def __init__(self, alg: AlgorithmSpec, mesh, g, *, axis: str,
                  capacity: int, m, spec, batch, max_subrounds: int,
-                 edges=None):
+                 placed: _PlacedEdges | None = None):
         from jax.sharding import PartitionSpec as Ps
-        from repro.graphs.csr import partition_edges
 
         self.P = mesh.shape[axis]
-        self.mesh = mesh
-        if edges is None:
-            edges = partition_edges(g, self.P)
-        (src, dst, w, val, eid), part = edges
-        self.arrays = (src, dst, w, val, eid)
-        self.layout = ShardLayout(self.P, part.block, src.shape[1],
-                                  g.num_vertices, g.num_edges)
-        ecfg = EngineConfig(self.P, part.block, capacity, axis=axis, m=m,
+        self.mesh, self.axis = mesh, axis
+        self.placed = placed or _place_edges(
+            g, mesh, axis, balance=_balanced(alg, self.P, batch))
+        # the numbering, if any, rides after the edge arrays
+        self.arrays = self.placed.arrays + self.placed.order
+        self.layout = layout = self.placed.layout
+        block = layout.block
+        ecfg = EngineConfig(self.P, block, capacity, axis=axis, m=m,
                             spec=spec, batch=batch)
-        self.state0, self.scalars0 = alg.init(g, self.layout)
+        # the loop is traced for the shapes of this initial state; every
+        # run of the runner brings its own from ``alg.init``
+        state0, scalars0 = alg.init(g, layout)
         self.tuner = None
         if ecfg.commit_spec.backend == C.AUTO:
             # stage-1 calibration BEFORE tracing: per-shard commits see a
             # [block] state slice and up to P*C routed messages/sub-round
-            leaf = jax.tree_util.tree_leaves(self.state0)[0]
+            leaf = jax.tree_util.tree_leaves(state0)[0]
             self.tuner = AT.policy_for(
                 ecfg.commit_spec,
-                jax.ShapeDtypeStruct((part.block,), leaf.dtype),
+                jax.ShapeDtypeStruct((block,), leaf.dtype),
                 n=min(self.P * capacity, g.num_edges or 1),
                 axis_width=batch.race_width if batch is not None else 1)
             ecfg = dataclasses.replace(ecfg, spec=None, tuner=self.tuner)
-        self.max_rounds = int(alg.max_rounds(g, self.layout))
+        self.max_rounds = int(alg.max_rounds(g, layout))
         tuner = self.tuner
-        # wave telemetry tap, decided AT TRACE TIME (a _Runner is built
-        # per run_distributed call, so flipping REPRO_TRACE takes effect
+        # wave telemetry tap, decided AT TRACE TIME (whether it is on is
+        # part of the runner's key, so flipping REPRO_TRACE takes effect
         # on the next run): one unordered io_callback per round per
         # shard — unordered so a multi-device mesh never serializes on
         # the host; the round index rides in the payload
@@ -596,16 +659,14 @@ class _Runner:
             edges = EdgeSlice(
                 src=src_l[0], dst=dst_l[0], weight=w_l[0], valid=val_l[0],
                 eid=eid_l[0],
-                my_src=jnp.clip(src_l[0] - shard * part.block, 0,
-                                part.block - 1))
+                my_src=jnp.clip(src_l[0] - shard * block, 0, block - 1))
 
             def cond(c):
                 return c[-1] & (c[-2] < limit)
 
             def body(c):
                 state, scalars, conflicts, subrounds, dall, level, it, _ = c
-                rt = WaveRuntime(ecfg, self.layout, max_subrounds,
-                                 level=level)
+                rt = WaveRuntime(ecfg, layout, max_subrounds, level=level)
                 state, scalars, active = alg.round_fn(rt, edges, state,
                                                       scalars, it)
                 if trace_cb is not None:
@@ -626,15 +687,25 @@ class _Runner:
             out = jax.lax.while_loop(cond, body, (state, scalars) + carry)
             return out[:2], out[2:]
 
-        st_specs = jax.tree.map(lambda _: Ps(axis), self.state0)
-        sc_specs = jax.tree.map(lambda _: Ps(), self.scalars0)
+        st_specs = jax.tree.map(lambda _: Ps(axis), state0)
+        sc_specs = jax.tree.map(lambda _: Ps(), scalars0)
         fn = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(st_specs, sc_specs, (Ps(),) * 6, Ps())
             + (Ps(axis),) * 5,
             out_specs=((st_specs, sc_specs), (Ps(),) * 6),
             check_vma=False)
-        self._jfn = jax.jit(fn)
+        if self.placed.order:
+            def renumbered(state, scalars, carry, limit, *arrays):
+                *edge_arrays, to_orig, to_int = arrays
+                state = jax.tree.map(lambda x: x[to_orig], state)
+                (state, scalars), carry = fn(state, scalars, carry, limit,
+                                             *edge_arrays)
+                return ((jax.tree.map(lambda x: x[to_int], state), scalars),
+                        carry)
+            self._jfn = jax.jit(renumbered)
+        else:
+            self._jfn = jax.jit(fn)
 
     def zero_carry(self) -> tuple:
         z = jnp.zeros((), jnp.int32)
@@ -653,6 +724,84 @@ class _Runner:
             return jnp.full((), -1, jnp.int32)
         ms = jnp.asarray([m or 0 for m in self.tuner.ladder], jnp.int32)
         return ms[jnp.clip(level, 0, len(self.tuner.ladder) - 1)]
+
+
+class RunnerCache:
+    """The few most recently used runners, so every search on one graph
+    and mesh reuses one partition and one compiled loop: Graph500's
+    kernel 1 (graph construction) runs once, not inside every kernel-2
+    search.  Bounded, least recently used out first, so a service with
+    many graphs does not pin their shards in HBM.
+
+    An entry holds its graph and ``edges`` argument by strong reference
+    and matches them with ``is``; the rest of the key is compared by
+    value, the ``round_fn``/``max_rounds`` callables by identity.  Only
+    runners of a ``reusable`` :class:`AlgorithmSpec` are kept: any other
+    run's runner is built and dropped on return, so a round function
+    that captures per-call data never hits a stale entry, pins no shards
+    and evicts no runner that can be found again (it still shares the
+    placed partition of a kept runner on its graph).  ``builds`` and
+    ``hits`` count lookups."""
+
+    def __init__(self, size: int):
+        self.size = size
+        self._entries: collections.OrderedDict = collections.OrderedDict()
+        self.builds = self.hits = 0
+
+    def __len__(self) -> int:
+        return len(self._entries)
+
+    def placed(self, g, edges, mesh, axis: str,
+               balance: bool) -> _PlacedEdges | None:
+        """The placed partition a cached runner holds for this graph,
+        ``edges`` argument, mesh axis and numbering, if any."""
+        for g_, edges_, r in self._entries.values():
+            if g_ is g and edges_ is edges and r.mesh == mesh \
+                    and r.axis == axis and bool(r.placed.order) == balance:
+                return r.placed
+        return None
+
+    def get(self, key: tuple, g, edges, build: Callable[[], _Runner], *,
+            keep: bool = True):
+        hit = self._entries.get(key) if keep else None
+        if hit is not None and hit[0] is g and hit[1] is edges:
+            self._entries.move_to_end(key)
+            self.hits += 1
+            return hit[2]
+        with OT.span("runner_build", cat="engine"):
+            r = build()
+        self.builds += 1
+        if not keep:
+            return r
+        self._entries[key] = (g, edges, r)
+        self._entries.move_to_end(key)
+        while len(self._entries) > self.size:
+            self._entries.popitem(last=False)
+        return r
+
+
+RUNNERS = RunnerCache(size=4)
+
+
+def _switches() -> tuple:
+    """The program's ``REPRO_*`` switches, which the loop reads while it
+    is traced: the wave tap (``REPRO_TRACE``), the sanitizer, the tuner
+    and the bucket counter."""
+    return tuple(sorted((k, v) for k, v in os.environ.items()
+                        if k.startswith("REPRO_")))
+
+
+def _runner(alg: AlgorithmSpec, mesh, g, *, axis: str, capacity: int, m,
+            spec, batch, max_subrounds: int, edges=None,
+            placed: _PlacedEdges | None = None) -> _Runner:
+    """The cached runner of this run's key, built on a miss; a runner of
+    an ``alg`` that is not ``reusable`` is built every time, never kept."""
+    key = (id(g), id(edges), mesh, axis, alg.round_fn, alg.max_rounds,
+           capacity, m, spec, batch, max_subrounds, _switches())
+    return RUNNERS.get(key, g, edges, lambda: _Runner(
+        alg, mesh, g, axis=axis, capacity=capacity, m=m, spec=spec,
+        batch=batch, max_subrounds=max_subrounds, placed=placed),
+        keep=alg.reusable)
 
 
 def _shrink_mesh(mesh, axis: str, new_size: int):
@@ -727,6 +876,20 @@ def run_distributed(alg: AlgorithmSpec, mesh, g, *,
     need the lane layout (Boruvka's edge-state finalize) partition only
     once.
 
+    **Runner reuse.**  For a ``reusable`` ``alg`` (its round function
+    captures nothing per call; the source enters through ``init``'s
+    state) the partition, its shards placed on the mesh, the tuner's
+    policy and the jitted round loop are built once and kept in
+    :data:`RUNNERS`, keyed by the graph and ``edges`` objects, the mesh
+    and axis, ``alg.round_fn`` and ``alg.max_rounds`` (by identity), the
+    resolved capacity, ``m``, ``spec`` (``spec.trace`` among it),
+    ``batch``, ``max_subrounds`` and the ``REPRO_*`` switches
+    (``REPRO_TRACE``'s wave tap among them).  A run then pays only
+    ``alg.init`` and the dispatch, so every query on a graph runs
+    through one compiled loop.  Any other ``alg`` builds its runner per
+    call and drops it on return; runners over the same graph, edges and
+    mesh share one placed partition.
+
     ``g`` may be a :class:`repro.graphs.csr.GraphSet`: the run executes
     over its disjoint-union graph (per-graph CSR slices gathered from the
     stacked edge arrays), which IS the graph-batch axis — flat union ids
@@ -749,9 +912,12 @@ def run_distributed(alg: AlgorithmSpec, mesh, g, *,
     snapshot onto it (see :func:`_remap_state`; per-edge state restarts
     from round 0), and finishes there.  ``DistributedResult.degraded``
     reports it.  With neither parameter set the loop runs single-shot and
-    any error propagates at once.
+    any error propagates at once: the loop is dispatched once and the
+    result returned without waiting for the device (a ``capacity="auto"``
+    run below its cap still reads its sub-round count back for the
+    feedback), so a caller can have its next run queued behind it.
     """
-    from repro.graphs.csr import GraphSet, partition_edges
+    from repro.graphs.csr import GraphSet
 
     if isinstance(g, GraphSet):
         batch = batch if batch is not None else g.axis
@@ -763,18 +929,19 @@ def run_distributed(alg: AlgorithmSpec, mesh, g, *,
         # here instead of executing the mesh program.
         raise LintCapture(alg, g, batch)
     P = mesh.shape[axis]
-    if edges is None:
-        edges = partition_edges(g, P)
+    balance = edges is None and _balanced(alg, P, batch)
+    placed = (RUNNERS.placed(g, edges, mesh, axis, balance)
+              or _place_edges(g, mesh, axis, edges, balance=balance))
     auto_cap = capacity == "auto"
     if auto_cap:
-        shard_edges = edges[0][0].shape[1]       # [P, emax] src array
+        shard_edges = placed.layout.emax
         cap = capacity_cap(shard_edges)
         capacity = auto_capacity(g, P, shard_edges)
     kw = dict(axis=axis, capacity=capacity, m=m, spec=spec, batch=batch,
               max_subrounds=max_subrounds)
-    with OT.span("runner_build", cat="engine"):
-        r = _Runner(alg, mesh, g, edges=edges, **kw)
-    state, scalars, carry = r.state0, r.scalars0, r.zero_carry()
+    r = _runner(alg, mesh, g, edges=edges, placed=placed, **kw)
+    state, scalars = alg.init(g, r.layout)
+    carry = r.zero_carry()
     degraded, faults, chunk_i = False, 0, 0
     chunk = (snapshot_rounds if snapshot_rounds
              else max(r.max_rounds, 1))
@@ -783,7 +950,13 @@ def run_distributed(alg: AlgorithmSpec, mesh, g, *,
     # host drop; otherwise the first error (a compile failure, an OOM)
     # propagates instead of shrinking the mesh or retrying in place
     tolerate = fault_injector is not None or snapshot_rounds is not None
-    while bool(carry[5]) and int(carry[4]) < r.max_rounds:
+    if not tolerate:
+        # single shot: one dispatch and no wait on the host, so a caller
+        # can queue its next run behind this one
+        with OT.span("chunk", cat="engine"):
+            state, scalars, carry = r.run(state, scalars, carry,
+                                          r.max_rounds)
+    while tolerate and bool(carry[5]) and int(carry[4]) < r.max_rounds:
         limit = min(int(carry[4]) + chunk, r.max_rounds)
         try:
             if fault_injector is not None:
@@ -810,21 +983,20 @@ def run_distributed(alg: AlgorithmSpec, mesh, g, *,
             if r.P > 1:
                 new_mesh = _shrink_mesh(r.mesh, axis, r.P - 1)
                 old_layout = r.layout
-                with OT.span("runner_build", cat="engine"):
-                    r = _Runner(alg, new_mesh, g, **kw)
+                r = _runner(alg, new_mesh, g, **kw)
                 remapped = _remap_state(alg, g, old_layout, r.layout,
                                         state)
                 if remapped is None:
                     # per-edge state can't be re-homed: restart the
                     # query from round 0 on the surviving mesh
-                    state, scalars = r.state0, r.scalars0
+                    state, scalars = alg.init(g, r.layout)
                     carry = r.zero_carry()
                 else:
                     state = remapped
             # P == 1: nothing to shrink — retry the snapshot in place
         chunk_i += 1
     conflicts, subrounds, dall, level, rounds, _ = carry
-    if auto_cap:
+    if auto_cap and capacity < cap:      # at the cap there is no growing
         _capacity_feedback(g, P, capacity, int(subrounds), int(rounds),
                            cap)
     return DistributedResult(state=state, scalars=scalars, rounds=rounds,

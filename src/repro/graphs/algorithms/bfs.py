@@ -132,6 +132,24 @@ def multi_source_bfs(g: Graph, sources, *, commit: str = "coarse",
     return BfsResult(dist, rounds, nmsg, ncf, nap)
 
 
+@partial(jax.jit, static_argnums=0)
+def _distributed_bfs_state(vpad: int, source):
+    return {"dist": jnp.full((vpad,), INF, jnp.int32).at[source].set(0),
+            "frontier": jnp.zeros((vpad,), bool).at[source].set(True)}
+
+
+def _distributed_bfs_round(rt, e, st, sc, it):
+    dist = st["dist"]
+    active = st["frontier"][e.my_src] & e.valid
+    dist2, _ = rt.wave(dist, e.dst, dist[e.my_src] + 1, active, op="min")
+    changed = dist2 != dist
+    return {"dist": dist2, "frontier": changed}, sc, rt.any(changed)
+
+
+def _vpad_rounds(g, layout) -> int:
+    return layout.vpad
+
+
 def distributed_bfs(mesh, g: Graph, source: int, *,
                     capacity: int | str = 4096,
                     m: int | None = None, axis: str = "data",
@@ -146,24 +164,22 @@ def distributed_bfs(mesh, g: Graph, source: int, *,
     :func:`repro.core.engine.telemetry_return`.  ``snapshot_rounds``/``fault_injector``
     enable the engine's degraded-mesh mode (survive a host drop by
     shrinking the mesh and replaying the last round snapshot — see
-    :func:`repro.core.engine.run_distributed`)."""
+    :func:`repro.core.engine.run_distributed`).
+
+    The round function and the round cap are module-level and the source
+    enters only through ``init``'s state, so every search on one graph
+    and mesh reuses one partition and one compiled loop
+    (``repro.core.engine.RUNNERS``).  Over several shards the vertices are
+    numbered so that every owner range holds an equal share of the edges
+    (``AlgorithmSpec.balance``); distances come back by vertex id."""
     from repro.core.engine import (AlgorithmSpec, run_distributed,
                                    telemetry_return)
 
     def init(g, layout):
-        dist0 = jnp.full((layout.vpad,), INF, jnp.int32).at[source].set(0)
-        frontier0 = jnp.zeros((layout.vpad,), bool).at[source].set(True)
-        return {"dist": dist0, "frontier": frontier0}, {}
+        return _distributed_bfs_state(layout.vpad, source), {}
 
-    def round_fn(rt, e, st, sc, it):
-        dist = st["dist"]
-        active = st["frontier"][e.my_src] & e.valid
-        dist2, _ = rt.wave(dist, e.dst, dist[e.my_src] + 1, active, op="min")
-        changed = dist2 != dist
-        return {"dist": dist2, "frontier": changed}, sc, rt.any(changed)
-
-    alg = AlgorithmSpec("bfs", "FF&MF", init, round_fn,
-                        lambda g, layout: layout.vpad)
+    alg = AlgorithmSpec("bfs", "FF&MF", init, _distributed_bfs_round,
+                        _vpad_rounds, reusable=True, balance=True)
     res = run_distributed(alg, mesh, g, capacity=capacity, m=m, axis=axis,
                           spec=spec, max_subrounds=max_subrounds,
                           snapshot_rounds=snapshot_rounds,

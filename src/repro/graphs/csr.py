@@ -239,3 +239,71 @@ def partition_edges(g: Graph, num_shards: int):
         valid[p, :n] = True
         eid[p, :n] = all_eids[m]
     return (s_out, d_out, w_out, valid, eid), Partition(num_shards, block)
+
+
+def balanced_numbering(degrees, num_shards: int):
+    """A renumbering of the vertices under which the contiguous owner
+    ranges of :func:`partition_edges` hold equal shares of the edges.
+
+    Vertices are dealt by degree, heaviest first, to the shards in snake
+    order (0..P-1, then P-1..0, ...), each into the next local row of
+    its shard; the ``vpad - V`` padding ids take the rows left over.  A
+    shard's edge count is then a function of the sorted degree sequence
+    alone, so it is the same under every labelling of the graph (with
+    plain owner ranges the fullest shard moves with the labels).
+    Returns ``(to_orig, to_int)``, int32 ``[vpad]`` inverse permutations:
+    internal id ``u`` is vertex ``to_orig[u]``, vertex ``v`` is internal
+    id ``to_int[v]``."""
+    deg = np.asarray(degrees)
+    v, p = deg.shape[0], num_shards
+    block = -(-v // p)
+    vpad = p * block
+    k, j = np.divmod(np.arange(v, dtype=np.int64), p)
+    shard = np.where(k % 2 == 0, j, p - 1 - j)
+    to_int = np.empty(vpad, np.int64)
+    to_int[np.argsort(-deg, kind="stable")] = shard * block + k
+    free = np.ones(vpad, bool)
+    free[to_int[:v]] = False
+    to_int[v:] = np.flatnonzero(free)
+    to_orig = np.empty(vpad, np.int64)
+    to_orig[to_int] = np.arange(vpad)
+    return to_orig.astype(np.int32), to_int.astype(np.int32)
+
+
+def partition_edges_balanced(g: Graph, num_shards: int):
+    """:func:`partition_edges` of ``g`` renumbered by
+    :func:`balanced_numbering`: ``src``/``dst`` are internal ids, each
+    shard's lanes sorted by internal source; ``eid`` stays the original
+    edge index.  Returns ``(arrays, Partition, (to_orig, to_int))``."""
+    indptr = np.asarray(g.indptr, np.int64)
+    deg = np.diff(indptr)
+    to_orig, to_int = balanced_numbering(deg, num_shards)
+    vpad = to_orig.shape[0]
+    block = vpad // num_shards
+    # each vertex's CSR row, in internal order (padding ids have none)
+    real = to_orig < g.num_vertices
+    lens = np.where(real, deg[np.minimum(to_orig, g.num_vertices - 1)], 0)
+    starts = indptr[np.minimum(to_orig, g.num_vertices)]
+    ends = np.cumsum(lens)
+    idx = np.repeat(starts - (ends - lens), lens) + np.arange(ends[-1])
+    src = np.repeat(np.arange(vpad, dtype=np.int32), lens)
+    dst = to_int[np.asarray(g.dst)[idx]]
+    w = np.asarray(g.weights)[idx]
+    bounds = np.concatenate([[0], ends[block - 1::block]])
+    counts = np.diff(bounds)
+    emax = max(int(counts.max()), 1)
+    s_out = np.zeros((num_shards, emax), np.int32)
+    d_out = np.zeros((num_shards, emax), np.int32)
+    w_out = np.zeros((num_shards, emax), np.float32)
+    valid = np.zeros((num_shards, emax), bool)
+    eid = np.full((num_shards, emax), g.num_edges, np.int32)
+    for p in range(num_shards):
+        lo, hi = bounds[p], bounds[p + 1]
+        n = hi - lo
+        s_out[p, :n] = src[lo:hi]
+        d_out[p, :n] = dst[lo:hi]
+        w_out[p, :n] = w[lo:hi]
+        valid[p, :n] = True
+        eid[p, :n] = idx[lo:hi]
+    return ((s_out, d_out, w_out, valid, eid), Partition(num_shards, block),
+            (to_orig, to_int))

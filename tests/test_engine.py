@@ -41,6 +41,48 @@ def test_snapshot_runs_still_retry_faults(monkeypatch):
     assert len(calls) == 9          # max_faults=8 retries, then raise
 
 
+class _Unread:
+    """A loop-carry scalar that JAX can compute with but the host cannot
+    read: ``bool``/``int``/``float``/``np.asarray`` on it fail."""
+
+    def __init__(self, a):
+        self.a = a
+
+    def __jax_array__(self):
+        return self.a
+
+    def _read(self, *_):
+        raise AssertionError("loop carry read back on the host")
+
+    __bool__ = __int__ = __index__ = __float__ = __array__ = _read
+
+
+@pytest.mark.parametrize("capacity", [64, "auto"])
+def test_single_shot_run_returns_without_reading_the_device(monkeypatch,
+                                                            capacity):
+    """Without fault tolerance the loop is dispatched once and the result
+    comes back with nothing read on the host (``"auto"`` is at its cap on
+    one shard, so no sub-round feedback is due): a caller can queue its
+    next run behind this one."""
+    import jax.numpy as jnp
+    real, calls = E._Runner.run, []
+
+    def run(self, state, scalars, carry, limit):
+        calls.append(limit)
+        state, scalars, carry = real(self, state, scalars, carry, limit)
+        return state, scalars, tuple(_Unread(c) for c in carry)
+
+    monkeypatch.setattr(E._Runner, "run", run)
+    g = kronecker(7, 8, seed=2)
+    dist, rounds, res = distributed_bfs(make_host_mesh(1, 1), g, 3,
+                                        capacity=capacity, telemetry=True)
+    assert len(calls) == 1
+    np.testing.assert_array_equal(np.asarray(dist, np.int64),
+                                  bfs_reference(g, 3))
+    assert int(jnp.asarray(rounds)) > 1
+    assert bool(jnp.asarray(res.delivered_all))
+
+
 def test_injected_fault_on_one_device_retries_in_place():
     g = kronecker(7, 8, seed=2)
     root = int(np.argmax(np.asarray(g.degrees)))

@@ -537,7 +537,7 @@ def test_trace_off_clean_engine_and_control(monkeypatch):
         r = E._Runner(cap.alg, _mesh1(), cap.g, axis="data", capacity=64,
                       m=8, spec=spec, batch=cap.batch, max_subrounds=8)
         return str(jax.make_jaxpr(r._jfn)(
-            r.state0, r.scalars0, r.zero_carry(),
+            *cap.alg.init(cap.g, r.layout), r.zero_carry(),
             jnp.asarray(1, jnp.int32), *r.arrays))
 
     assert "callback" not in jx(CommitSpec())
